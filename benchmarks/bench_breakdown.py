@@ -53,8 +53,8 @@ def run_cell(fam, n, L, R, G) -> Dict:
     env["PYTHONPATH"] = SRC
     r = subprocess.run([sys.executable, "-c", _SUB, fam, str(n), str(L), str(R), str(G)],
                        capture_output=True, text=True, timeout=1200, env=env)
-    if r.returncode != 0:
-        return {"error": r.stderr[-300:]}
+    if r.returncode != 0:  # the parent stays JAX-free; a failed child fails
+        raise RuntimeError(f"child {fam} n={n} failed:\n{r.stderr[-2000:]}")
     return json.loads(r.stdout.strip().splitlines()[-1])
 
 
@@ -71,9 +71,6 @@ def main(argv=None):
     for extra, (R, G) in [(1, (1, 0)), (2, (2, 0)), (3, (2, 1))]:
         n = L + extra
         res = run_cell(fam, n, L, R, G)
-        if "error" in res:
-            print(f"{fam},{n},{1 << extra},ERROR")
-            continue
         tc, tm, tl = res["t_compute_s"], res["t_memory_s"], res["t_collective_s"]
         frac = tl / (tl + max(tc, tm))
         print(f"{fam},{n},{1 << extra},{res['stages']},"

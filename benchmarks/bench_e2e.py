@@ -14,7 +14,6 @@ import json
 import os
 import subprocess
 import sys
-import time
 from typing import Dict, List
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -42,34 +41,45 @@ print(json.dumps({"time_s": dt, "stages": plan.n_stages,
 """
 
 
-def run_cell(fam: str, n: int, L: int, R: int, G: int, reps: int = 3) -> Dict:
+# single device, one kernel per gate (no fusion): the unkernelized
+# comparison point
+_SUB_PERGATE = r"""
+import json, time, sys
+import jax
+from repro.core.generators import FAMILIES
+from repro.sim.statevector import simulate
+
+fam, n, reps = sys.argv[1], int(sys.argv[2]), int(sys.argv[3])
+c = FAMILIES[fam](n)
+fn = jax.jit(lambda: simulate(c))
+fn().block_until_ready()
+t0 = time.time()
+for _ in range(reps):
+    out = fn()
+out.block_until_ready()
+print(json.dumps({"time_s": (time.time() - t0) / reps}))
+"""
+
+
+def _child(code: str, args, devices: int = 1) -> Dict:
+    """Run one measurement in a child process (the parent never imports
+    JAX, so a child can own the device); a failed child fails the run."""
     env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={1 << (R + G)}"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = SRC
-    r = subprocess.run(
-        [sys.executable, "-c", _SUB, fam, str(n), str(L), str(R), str(G), str(reps)],
-        capture_output=True, text=True, timeout=1200, env=env,
-    )
+    r = subprocess.run([sys.executable, "-c", code, *map(str, args)],
+                       capture_output=True, text=True, timeout=1200, env=env)
     if r.returncode != 0:
-        return {"error": r.stderr[-400:]}
+        raise RuntimeError(f"child {args} failed:\n{r.stderr[-2000:]}")
     return json.loads(r.stdout.strip().splitlines()[-1])
 
 
-def per_gate_baseline(fam: str, n: int, reps: int = 3) -> Dict:
-    """Single-device, one kernel per gate (no fusion) — the unkernelized
-    comparison point."""
-    import jax
-    from repro.core.generators import FAMILIES
-    from repro.sim.statevector import simulate
+def run_cell(fam: str, n: int, L: int, R: int, G: int, reps: int = 3) -> Dict:
+    return _child(_SUB, (fam, n, L, R, G, reps), devices=1 << (R + G))
 
-    c = FAMILIES[fam](n)
-    fn = jax.jit(lambda: simulate(c))
-    fn().block_until_ready()
-    t0 = time.time()
-    for _ in range(reps):
-        out = fn()
-    out.block_until_ready()
-    return {"time_s": (time.time() - t0) / reps}
+
+def per_gate_baseline(fam: str, n: int, reps: int = 3) -> Dict:
+    return _child(_SUB_PERGATE, (fam, n, reps))
 
 
 def main(argv=None):
@@ -91,9 +101,6 @@ def main(argv=None):
         R = min(extra, 2)
         G = extra - R
         res = run_cell(fam, n, L, R, G, args.reps)
-        if "error" in res:
-            print(f"{fam},{n},{1 << extra},ERROR,{res['error'][:80]}")
-            continue
         gates = FAMILIES[fam](n).n_gates
         rows.append(res)
         print(f"{fam},{n},{1 << extra},{res['time_s']:.4f},{res['stages']},"
@@ -104,9 +111,8 @@ def main(argv=None):
     n = L
     atlas = run_cell(fam, n, L, 0, 0, args.reps)
     pg = per_gate_baseline(fam, n, args.reps)
-    if "error" not in atlas:
-        print(f"{fam},{n},{atlas['time_s']:.4f},{pg['time_s']:.4f},"
-              f"{pg['time_s'] / atlas['time_s']:.2f}")
+    print(f"{fam},{n},{atlas['time_s']:.4f},{pg['time_s']:.4f},"
+          f"{pg['time_s'] / atlas['time_s']:.2f}")
     return rows
 
 

@@ -62,8 +62,8 @@ def run_cell(fam: str, n: int, L: int, R: int, G: int, devices: int, shm_q: int 
     env["PYTHONPATH"] = SRC
     r = subprocess.run([sys.executable, "-c", _SUB, fam, str(n), str(L), str(R), str(G), str(shm_q)],
                        capture_output=True, text=True, timeout=3600, env=env)
-    if r.returncode != 0:
-        return {"error": r.stderr[-2000:]}
+    if r.returncode != 0:  # the parent stays JAX-free; a failed child fails
+        raise RuntimeError(f"child {fam} n={n} failed:\n{r.stderr[-2000:]}")
     return json.loads(r.stdout.strip().splitlines()[-1])
 
 
@@ -89,9 +89,6 @@ def main(argv=None):
     path = os.path.join(OUT, f"sim__{args.family}{n}__{tag}.json")
     with open(path, "w") as f:
         json.dump(res, f, indent=1)
-    if "error" in res:
-        print("FAILED:", res["error"][:500])
-        return res
     rl = res["roofline"]
     print(f"sim dry-run {args.family}({n}) on {devices} chips "
           f"(L/R/G={L}/{R}/{G}): {res['stages']} stages, "

@@ -43,6 +43,9 @@ def main() -> None:
              "the perf trajectory accumulates across commits)",
     )
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     skip = set(args.skip.split(",")) if args.skip else set()
 
     summary = []

@@ -10,8 +10,9 @@ TPU mapping:
   cost model's sweet spot sits at 7 qubits (see core/cost_model.py);
 * the state streams through VMEM in ``(BLOCK_M, K)`` tiles (double-buffered by
   the Pallas pipeline); U stays VMEM-resident across the whole grid;
-* complex arithmetic is planar fp32: 4 real matmuls, or 3 with the Karatsuba
-  trick (measured in EXPERIMENTS.md §Perf — trades one matmul for two adds).
+* complex arithmetic is planar fp32: 4 real matmuls;
+* every dot runs at ``Precision.HIGHEST`` (see :data:`repro.kernels.layout.PRECISION`):
+  at the default precision a TPU computes an f32 dot in one bf16 pass.
 """
 
 from __future__ import annotations
@@ -23,38 +24,23 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .layout import PRECISION
 
-def _kernel4(sre_ref, sim_ref, ure_ref, uim_ref, ore_ref, oim_ref):
+
+def _dot(a, b):
+    return jnp.dot(a, b, precision=PRECISION, preferred_element_type=jnp.float32)
+
+
+def _kernel(sre_ref, sim_ref, ure_ref, uim_ref, ore_ref, oim_ref):
     sre = sre_ref[...]
     sim = sim_ref[...]
     ure_t = ure_ref[...].T
     uim_t = uim_ref[...].T
-    f32 = jnp.float32
-    ore_ref[...] = (
-        jnp.dot(sre, ure_t, preferred_element_type=f32)
-        - jnp.dot(sim, uim_t, preferred_element_type=f32)
-    )
-    oim_ref[...] = (
-        jnp.dot(sre, uim_t, preferred_element_type=f32)
-        + jnp.dot(sim, ure_t, preferred_element_type=f32)
-    )
+    ore_ref[...] = _dot(sre, ure_t) - _dot(sim, uim_t)
+    oim_ref[...] = _dot(sre, uim_t) + _dot(sim, ure_t)
 
 
-def _kernel3(sre_ref, sim_ref, ure_ref, uim_ref, ore_ref, oim_ref):
-    # Karatsuba: (a+ib)(c+id) with 3 real products
-    sre = sre_ref[...]
-    sim = sim_ref[...]
-    ure_t = ure_ref[...].T
-    uim_t = uim_ref[...].T
-    f32 = jnp.float32
-    k1 = jnp.dot(sre + sim, ure_t, preferred_element_type=f32)
-    k2 = jnp.dot(sre, uim_t - ure_t, preferred_element_type=f32)
-    k3 = jnp.dot(sim, ure_t + uim_t, preferred_element_type=f32)
-    ore_ref[...] = k1 - k3
-    oim_ref[...] = k1 + k2
-
-
-@functools.partial(jax.jit, static_argnames=("block_m", "karatsuba", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
 def fused_matmul(
     sre: jnp.ndarray,
     sim: jnp.ndarray,
@@ -62,7 +48,6 @@ def fused_matmul(
     uim: jnp.ndarray,
     *,
     block_m: int = 512,
-    karatsuba: bool = False,
     interpret: bool = True,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """sre/sim: [M, K] fp32; ure/uim: [K, K] fp32. Returns planar result."""
@@ -72,14 +57,13 @@ def fused_matmul(
     grid = (m // bm,)
     state_spec = pl.BlockSpec((bm, k), lambda i: (i, 0))
     u_spec = pl.BlockSpec((k, k), lambda i: (0, 0))
-    body = _kernel3 if karatsuba else _kernel4
     out_shape = [
         jax.ShapeDtypeStruct((m, k), jnp.float32),
         jax.ShapeDtypeStruct((m, k), jnp.float32),
     ]
     return tuple(
         pl.pallas_call(
-            body,
+            _kernel,
             grid=grid,
             in_specs=[state_spec, state_spec, u_spec, u_spec],
             out_specs=[state_spec, state_spec],

@@ -1,7 +1,9 @@
-"""jit'd wrappers dispatching state-vector ops to the Pallas kernels.
+"""jit-traceable wrappers dispatching lane-dense state shards to the Pallas
+kernels.
 
-``interpret`` defaults to True off-TPU so the kernels execute (and are
-validated) on CPU; on a real TPU backend the same code lowers to Mosaic.
+Whether a kernel runs compiled or interpreted is decided when it is traced
+(:func:`interpret_mode`): interpreted on a CPU backend, compiled by Mosaic on
+a TPU, refused anywhere else. Nothing is decided at import time.
 """
 
 from __future__ import annotations
@@ -10,19 +12,16 @@ from typing import Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from .fusion import fused_matmul
+from .layout import choose_block_m
 from .shm import shm_apply
-
-INTERPRET = jax.default_backend() != "tpu"
 
 # Trace-time pallas_call emission counters: each wrapper bumps its counter
 # once per call site traced, so after `jit`-tracing an executor the counts
 # equal the number of kernel launches (= HBM read+write passes) in the
-# compiled program. Tests use this to prove an shm group of g gates costs
-# exactly ONE kernel launch.
-KERNEL_CALLS = {"fused": 0, "shm": 0}
+# compiled program. "interpreted" counts call sites traced in interpret mode.
+KERNEL_CALLS = {"fused": 0, "shm": 0, "interpreted": 0}
 
 
 def reset_kernel_counters() -> None:
@@ -34,89 +33,68 @@ def kernel_call_counts() -> dict:
     return dict(KERNEL_CALLS)
 
 
+def interpret_mode() -> bool:
+    """Trace-time choice: interpret on ``cpu``, compile on ``tpu``."""
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(f"Pallas kernels have no path on platform {platform!r}")
+
+
+def _interpret() -> bool:
+    interp = interpret_mode()
+    if interp:
+        KERNEL_CALLS["interpreted"] += 1
+    return interp
+
+
 def _to_planar(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     return jnp.real(x).astype(jnp.float32), jnp.imag(x).astype(jnp.float32)
 
 
-def _choose_block_m(m: int, k_cols: int, target_bytes: int = 1 << 21) -> int:
-    """Pick BLOCK_M so a (BM, K) fp32 tile is ~2 MiB and divides M."""
-    want = max(8, target_bytes // max(k_cols * 4, 1))
-    bm = 1
-    while bm * 2 <= min(want, m):
-        bm *= 2
-    while m % bm:
-        bm //= 2
-    return max(bm, 1)
+def fused_block_m(m: int, k: int = 128) -> int:
+    """Row block of :func:`fused_matmul` for an ``[m, k]`` planar state: 2
+    inputs + 2 outputs double buffered (8 planes) and 4 dot temporaries;
+    the two ``[k, k]`` gate planes double buffered."""
+    return choose_block_m(m, 4 * k, planes=12, fixed=2 * 2 * 4 * k * k)
 
 
-def apply_fused_shard(
-    view: jnp.ndarray, u: jnp.ndarray, local_bits: Sequence[int], karatsuba: bool = False
-) -> jnp.ndarray:
-    """Apply fused unitary ``u`` [K, K] (complex) to a local shard view
-    ((2,)*L complex array) on index bits ``local_bits`` via the Pallas MXU
-    kernel. Transposes the target bits to the lowest positions first."""
+def lane_matmul(x: jnp.ndarray, E, l: int) -> jnp.ndarray:
+    """``x @ E^T`` over the ``2^l`` lanes of one state through the Pallas
+    fused kernel. ``E``: ``[2^l, 2^l]``, or ``[G, 2^l, 2^l]`` with variant g
+    on the g-th row block (one kernel call per block)."""
     KERNEL_CALLS["fused"] += 1
-    L = view.ndim
-    k = len(local_bits)
-    lb = list(local_bits)
-    rest = [b for b in range(L - 1, -1, -1) if b not in lb]
-    # axes order: rest (desc) + gate bits desc => flat [M, K] with K-bit j = lb[j]
-    perm = [L - 1 - b for b in rest] + [L - 1 - b for b in reversed(lb)]
-    x = jnp.transpose(view, perm).reshape(1 << (L - k), 1 << k)
-    sre, sim = _to_planar(x)
-    ure, uim = _to_planar(u)
-    bm = _choose_block_m(x.shape[0], x.shape[1])
-    ore, oim = fused_matmul(
-        sre, sim, ure, uim, block_m=bm, karatsuba=karatsuba, interpret=INTERPRET
-    )
-    out = (ore + 1j * oim).astype(view.dtype).reshape([2] * L)
-    inv = np.argsort(perm)
-    return jnp.transpose(out, list(inv))
+    interp = _interpret()
+    cols = 1 << l
+    E = jnp.asarray(E)
+
+    def one(v, e):
+        rows = v.size // cols
+        sre, sim = _to_planar(v.reshape(rows, cols))
+        ure, uim = _to_planar(e)
+        ore, oim = fused_matmul(sre, sim, ure, uim,
+                                block_m=fused_block_m(rows, cols),
+                                interpret=interp)
+        return jax.lax.complex(ore, oim).astype(x.dtype)
+
+    if E.ndim == 2:
+        return one(x, E).reshape(x.shape)
+    blocks = x.reshape(E.shape[0], -1)
+    return jax.vmap(one)(blocks, E).reshape(x.shape)
 
 
-def apply_shm_shard(
-    view: jnp.ndarray,
-    gates: Sequence[Tuple[Tuple[int, ...], np.ndarray]],
-    window_bits: int,
-) -> jnp.ndarray:
-    """Apply a shared-memory kernel (gate list on the lowest ``window_bits``
-    bits; bits are window-relative) to a local shard view — one
-    ``pallas_call`` for the whole group."""
+def shm_kernel(x: jnp.ndarray,
+               gates: Sequence[Tuple[Tuple[int, ...], jnp.ndarray]],
+               window_bits: int) -> jnp.ndarray:
+    """ONE shm ``pallas_call`` over a lane-dense state ``x`` ``[rows,
+    2^l]`` whose group window is its lowest ``window_bits`` index bits (the
+    lanes and the lowest row bits); member ``bits`` are index positions
+    inside it. :func:`repro.sim.lanes.apply_shm_group` routes a window
+    there first."""
     KERNEL_CALLS["shm"] += 1
-    L = view.ndim
-    a = window_bits
-    x = view.reshape(1 << (L - a), 1 << a)
+    interp = _interpret()
     sre, sim = _to_planar(x)
-    bm = _choose_block_m(x.shape[0], x.shape[1], target_bytes=1 << 19)
-    ore, oim = shm_apply(sre, sim, gates, a, block_m=bm, interpret=INTERPRET)
-    return (ore + 1j * oim).astype(view.dtype).reshape((2,) * L)
-
-
-def apply_shm_group(
-    view: jnp.ndarray,
-    gates: Sequence[Tuple[Tuple[int, ...], jnp.ndarray]],
-    window: Sequence[int],
-) -> jnp.ndarray:
-    """Apply an shm group whose member gates act on arbitrary shard index
-    bits. ``window`` is the group's active bit set (ascending shard
-    positions); member gate ``bits`` are shard positions inside ``window``.
-
-    Transposes the window bits to the lowest positions, runs ONE shm
-    ``pallas_call`` over the whole group, and transposes back — the group
-    costs one HBM read+write pass regardless of its gate count.
-    """
-    L = view.ndim
-    w = list(window)
-    a = len(w)
-    pos_in_window = {b: i for i, b in enumerate(w)}
-    rel_gates = [
-        (tuple(pos_in_window[b] for b in bits), mat) for bits, mat in gates
-    ]
-    if w == list(range(a)):
-        return apply_shm_shard(view, rel_gates, a)
-    # transpose-in/out wrapper: window bits -> lowest a index bits
-    rest = [b for b in range(L - 1, -1, -1) if b not in pos_in_window]
-    perm = [L - 1 - b for b in rest] + [L - 1 - b for b in reversed(w)]
-    x = jnp.transpose(view, perm)
-    out = apply_shm_shard(x, rel_gates, a)
-    return jnp.transpose(out, list(np.argsort(perm)))
+    ore, oim = shm_apply(sre, sim, gates, window_bits, interpret=interp)
+    return jax.lax.complex(ore, oim).astype(x.dtype)

@@ -33,10 +33,8 @@ def shm_apply_ref(
     x = (sre + 1j * sim).astype(jnp.complex64)
     m = x.shape[0]
     view = x.reshape((m,) + (2,) * a)
-    from ..sim.apply import apply_matrix
-
     for bits, mat in gates:
-        # apply_matrix treats the *trailing* n dims as the bit view
+        # the trailing a axes are the window bits, highest first
         k = len(bits)
         mat = jnp.asarray(mat, dtype=jnp.complex64)
         mat_t = mat.reshape((2,) * (2 * k))

@@ -288,6 +288,9 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    from ..compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.demo or not args.port:
         return asyncio.run(run_demo(args))

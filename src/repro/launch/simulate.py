@@ -34,7 +34,9 @@ import time
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
+from ..compile_cache import enable_compile_cache
 from ..core.generators import FAMILIES, PARAM_FAMILIES
 from ..core.partition import partition
 from ..sim.statevector import fidelity, simulate
@@ -45,8 +47,21 @@ def _pjit_mesh(R: int, G: int):
     if R + G > 0 and len(jax.devices()) >= (1 << (R + G)):
         rd = 1 << (R // 2)
         rm = 1 << (R - R // 2)
-        return jax.make_mesh((1 << G, rd, rm), ("pod", "data", "model"))
+        return jax.make_mesh((1 << G, rd, rm), ("pod", "data", "model"),
+                             axis_types=(AxisType.Auto,) * 3)
     return None
+
+
+def _print_degraded(ex):
+    """Say so whenever the engine is not the one that was asked for."""
+    prov = getattr(ex, "provenance", None) or {}
+    if prov.get("degraded"):
+        print(f"DEGRADED: asked for {prov.get('requested_backend')}"
+              f"{'+pallas' if prov.get('requested_use_pallas') else ''}, "
+              f"running {prov.get('backend')}"
+              f"{'+pallas' if prov.get('use_pallas') else ''}")
+        for fb in prov.get("fallbacks", []):
+            print(f"  fallback {fb['from']} -> {fb['to']}: {fb['error']}")
 
 
 def _print_storage_summary(ex):
@@ -152,6 +167,7 @@ def main(argv=None):
     ap.add_argument("--vqe-lr", type=float, default=0.1)
     ap.add_argument("--vqe-seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     n = args.n
     L = args.L or (n - args.R - args.G)
@@ -217,6 +233,7 @@ def main(argv=None):
             backend_kw=backend_kw, storage=storage,
         )
         plan = ex.plan
+        _print_degraded(ex)
         st_cfg = getattr(ex.backend, "storage", None)
         if st_cfg is not None:
             budget = ("unbounded" if st_cfg.dram_bytes is None
@@ -389,6 +406,7 @@ def main(argv=None):
             from ..sim.offload import PerGateOffloadExecutor
 
             ex = PerGateOffloadExecutor(circ, L)
+        _print_degraded(ex)
     if args.executor == "pergate":
         out = ex.run()
     else:

@@ -87,6 +87,7 @@ class ServeConfig:
     # robustness (see README "Robustness")
     request_timeout_s: Optional[float] = None  # default per-request deadline
     verify_norm: bool = True  # post-run ||psi|| =~ 1 guard (per-request verify= overrides)
+    degrade: bool = True  # walk the engine degradation ladder on build failures
     retry_max: int = 2  # transient-failure retries per execution
     retry_base_s: float = 0.01  # backoff: min(cap, base * 2^attempt) * jitter
     retry_cap_s: float = 0.25
@@ -161,7 +162,7 @@ class WarmPool:
                 dtype=cfg.dtype, use_pallas=cfg.use_pallas,
                 staging_method=cfg.staging_method,
                 kernelize_method=cfg.kernelize_method,
-                cache=self.cache if admitted else None,
+                cache=self.cache if admitted else None, degrade=cfg.degrade,
             )
         except FaultError as e:
             self._build_failed(key.digest, e)

@@ -16,6 +16,8 @@ from typing import Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.layout import gather_bits, scatter_bits
+
 
 def axis_of_bit(n: int, p: int) -> int:
     return n - 1 - p
@@ -48,25 +50,6 @@ def apply_diag(psi_view: jnp.ndarray, diag: jnp.ndarray, bits: Sequence[int]) ->
     dst = [perm_axes[k - 1 - i] for i in range(k)]
     w = jnp.moveaxis(d.reshape((2,) * k + (1,) * (n - k)), src, dst)
     return psi_view * w
-
-
-def scatter_bits(values: np.ndarray, positions: Sequence[int]) -> np.ndarray:
-    """Vectorized bit scatter: deposit bit ``j`` of each value at position
-    ``positions[j]`` of the result (numpy index arithmetic, no Python loop
-    over values)."""
-    out = np.zeros_like(np.asarray(values, dtype=np.int64))
-    for j, p in enumerate(positions):
-        out |= ((values >> j) & 1) << p
-    return out
-
-
-def gather_bits(values: np.ndarray, positions: Sequence[int]) -> np.ndarray:
-    """Vectorized bit gather: bit ``j`` of the result is bit ``positions[j]``
-    of each value (inverse of :func:`scatter_bits`)."""
-    out = np.zeros_like(np.asarray(values, dtype=np.int64))
-    for j, p in enumerate(positions):
-        out |= ((values >> p) & 1) << j
-    return out
 
 
 def embed_matrix(mat: np.ndarray, positions: Sequence[int], k: int) -> np.ndarray:

@@ -48,7 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core import optimize as copt
@@ -75,6 +75,7 @@ from .compile import (
     bind_tensors_sweep,
     compile_plan,
 )
+from . import lanes
 from .shard_store import ShardStore, StorageConfig
 
 
@@ -100,73 +101,48 @@ def _dep_index(op: Op, G: int, R: int, L: int) -> Optional[jnp.ndarray]:
 
 
 def apply_op(
-    x: jnp.ndarray, op: Op, G: int, R: int, L: int, dtype, consts=None
+    x: jnp.ndarray, op: Op, G: int, R: int, L: int, dtype, consts=None,
+    use_pallas: bool = False,
 ) -> jnp.ndarray:
-    """x: [2^G, 2^R] + (2,)*L; ``consts`` maps ``Op.uid`` -> device tensor."""
+    """Apply one op to the whole lane-dense state ``x`` (2^(G+R+L)
+    amplitudes; the G+R non-local bits are the top row bits, one shard per
+    row block). ``consts`` maps ``Op.uid`` -> device tensor; a dep-batched
+    tensor supplies one variant per shard; ``use_pallas`` runs lane
+    contractions of fused ops in the Pallas MXU kernel."""
     if op.kind == "shm":
-        # non-Pallas fallback: members apply sequentially (same semantics,
-        # one einsum per member; GSPMD is free to fuse)
+        # non-Pallas shm group: members apply sequentially (same semantics)
         for m in op.gates:
-            x = apply_op(x, m, G, R, L, dtype, consts)
+            x = apply_op(x, m, G, R, L, dtype, consts, use_pallas)
         return x
-    k = len(op.local_bits)
     T = None if consts is None else consts.get(op.uid)
     if T is None:
         T = jnp.asarray(op.tensor, dtype=dtype)
     idx = _dep_index(op, G, R, L)
-
-    if op.kind == "scalar":
-        w = T[idx] if idx is not None else T[0]
-        return x * w.reshape(w.shape + (1,) * L) if idx is not None else x * w
-
-    if op.kind == "diag":
-        w = T[idx] if idx is not None else jnp.broadcast_to(T[0], (1, 1) + T.shape[1:])
-        shape = list(w.shape[:2]) + [
-            2 if ((1 << p) & sum(1 << b for b in op.local_bits)) else 1
-            for p in range(L - 1, -1, -1)
-        ]
-        return x * w.reshape(shape)
-
-    # fused
-    if idx is not None:
-        Tsel = T[idx]  # [2^G, 2^R, 2^k, 2^k]
-    else:
-        Tsel = T[0][None, None]  # [1, 1, 2^k, 2^k] broadcasts over g, r
-    Tv = Tsel.reshape(Tsel.shape[:2] + (2,) * (2 * k))
-    # integer einsum labels
-    lbl_g, lbl_r = 0, 1
-    lbl_loc = {p: 2 + (L - 1 - p) for p in range(L)}  # state axis label per bit
-    fresh = {p: 2 + L + i for i, p in enumerate(op.local_bits)}
-    s_labels = [lbl_g, lbl_r] + [lbl_loc[p] for p in range(L - 1, -1, -1)]
-    kq = list(op.local_bits)
-    t_labels = (
-        [lbl_g if idx is not None else 2 + L + 2 * L,
-         lbl_r if idx is not None else 3 + L + 2 * L]
-        + [fresh[p] for p in reversed(kq)]
-        + [lbl_loc[p] for p in reversed(kq)]
-    )
     if idx is None:
-        # broadcast dims get their own labels; use explicit size-1 axes
-        Tv = Tv.reshape(Tv.shape[2:])
-        t_labels = t_labels[2:]
-        out_labels = [lbl_g, lbl_r] + [
-            fresh.get(p, lbl_loc[p]) for p in range(L - 1, -1, -1)
-        ]
-        return jnp.einsum(Tv, t_labels, x, s_labels, out_labels)
-    out_labels = [lbl_g, lbl_r] + [
-        fresh.get(p, lbl_loc[p]) for p in range(L - 1, -1, -1)
-    ]
-    return jnp.einsum(Tv, t_labels, x, s_labels, out_labels)
+        w = T[0]
+    else:  # [2^G, 2^R, ...] -> one variant per shard (row block)
+        w = T[idx].reshape((1 << (G + R),) + T.shape[1:])
+    if op.kind == "scalar":
+        if idx is None:
+            return x * w
+        return lanes.apply_diag(x, w[:, None], (), lanes.lane_bits(L))
+    if op.kind == "diag":
+        return lanes.apply_diag(x, w, op.local_bits, lanes.lane_bits(L))
+    return _apply_fused(x, w, op.local_bits, L, use_pallas)
+
+
+def _apply_fused(x, u, bits, L: int, use_pallas: bool):
+    if use_pallas:
+        from ..kernels import ops as kops
+
+        return lanes.apply_unitary(x, u, bits, L, lane_matmul=kops.lane_matmul)
+    return lanes.apply_unitary(x, u, bits, L)
 
 
 def apply_remap(x: jnp.ndarray, spec: RemapSpec, n: int, G: int, R: int, L: int) -> jnp.ndarray:
-    """x packed [2^G, 2^R] + (2,)*L -> full bit transpose -> packed."""
-    full = x.reshape((2,) * n)
-    for p in spec.flip_bits:
-        full = jnp.flip(full, axis=n - 1 - p)
-    perm = [n - 1 - spec.src_bit_of[n - 1 - i] for i in range(n)]
-    full = jnp.transpose(full, perm)
-    return full.reshape((1 << G, 1 << R) + (2,) * L)
+    """Bit permutation with flips over all n bits of the lane-dense state
+    (lanes = the lowest min(7, L) bits)."""
+    return lanes.permute(x, spec.src_bit_of, spec.flip_bits, lanes.lane_bits(L))
 
 
 # ======================================================================
@@ -268,23 +244,30 @@ def _build_remap_plan(spec: RemapSpec, n: int, L: int) -> RemapPlan:
     )
 
 
-def _apply_remap_plan(view, rp: RemapPlan, L: int, axis_names) -> jnp.ndarray:
-    """Run one remap choreography on a per-device (2,)*L view."""
+def _axes_to_bits(perm: Sequence[int], L: int) -> List[int]:
+    """A transpose ``perm`` of a (2,)*L view (axis i <-> bit L-1-i) as a
+    ``src_bit_of`` bit permutation."""
+    return [L - 1 - perm[L - 1 - p] for p in range(L)]
+
+
+def _apply_remap_plan(shard, rp: RemapPlan, L: int, axis_names) -> jnp.ndarray:
+    """Run one remap choreography on a per-device flat [2^L] shard (the
+    plan's view axes map to bits; local transposes take the lane-dense
+    route)."""
     m = rp.m
-    for ax in rp.local_flip_axes:
-        view = jnp.flip(view, axis=ax)
-    x = jnp.transpose(view, rp.pre_perm)
+    x = lanes.permute(shard, _axes_to_bits(rp.pre_perm, L),
+                      [L - 1 - ax for ax in rp.local_flip_axes])
     if m:
-        x = x.reshape((1 << m, 1 << (L - m)))
+        # 2^m chunks of the top bits, lanes kept whole when they fit
+        l = lanes.lane_bits(L)
+        x = x.reshape((1 << m, -1, 1 << l) if L - m >= l else (1 << m, -1))
         x = lax.all_to_all(x, rp.a2a_axes, split_axis=0, concat_axis=0, tiled=True)
         # tiled=True keeps dim0 = 2^m (split into 2^m chunks, exchanged,
         # re-concatenated along the same axis)
     if rp.ppermute is not None:
         x = lax.ppermute(x, axis_names, perm=list(rp.ppermute))
-    x = x.reshape((2,) * L)
-    for ax in rp.post_flip_axes:
-        x = jnp.flip(x, axis=ax)
-    return jnp.transpose(x, rp.post_perm)
+    return lanes.permute(x.reshape(shard.shape), _axes_to_bits(rp.post_perm, L),
+                         [L - 1 - ax for ax in rp.post_flip_axes])
 
 
 # ======================================================================
@@ -340,20 +323,17 @@ def _build_shard_fn(op_shapes: Tuple, L: int, batched: bool = False,
     leading axis — element p of the block is transformed by binding p's
     tensors (the fused parameter-sweep path)."""
 
+    l = lanes.lane_bits(L)
+
     def apply_one(x, kind, local_bits, T):
-        k = len(local_bits)
         if kind == "scalar":
             return x * T
         if kind == "diag":
-            d = T.reshape((2,) * k)
-            shape = [2 if p in local_bits else 1 for p in range(L - 1, -1, -1)]
-            return x * d.reshape(shape)
-        from .apply import apply_matrix
-
-        return apply_matrix(x, T, list(local_bits))
+            return lanes.apply_diag(x, T, local_bits, l)
+        return lanes.apply_unitary(x, T, local_bits, L)
 
     def fn(shard, *tensors):
-        x = shard.reshape((2,) * L)
+        x = shard.reshape(-1, 1 << l)  # lane-dense (rows, lanes)
         ti = 0
         for entry in op_shapes:
             if entry[0] == "shm":
@@ -435,6 +415,24 @@ def _shm_operands(op: Op, select: Callable):
 # ======================================================================
 
 
+def _place_state(psi0, shape, dtype, sharding):
+    """The initial state at ``shape`` on the backend's placement, never
+    whole on one device first: |0..0> is built under ``jit`` with
+    ``out_shardings``; a host array goes to each device as its own shard."""
+    if psi0 is None:
+        def zero():
+            return jnp.zeros(shape, dtype).reshape(-1).at[0].set(1).reshape(shape)
+
+        if sharding is None:
+            return jax.jit(zero)()
+        return jax.jit(zero, out_shardings=sharding)()
+    if isinstance(psi0, jax.Array):
+        x = psi0.astype(dtype).reshape(shape)
+    else:
+        x = np.asarray(psi0, dtype=np.dtype(dtype)).reshape(shape)
+    return jax.device_put(x, sharding) if sharding is not None else jnp.asarray(x)
+
+
 class Backend:
     """One execution substrate under the engine's stage loop.
 
@@ -497,7 +495,10 @@ class Backend:
 class PjitBackend(Backend):
     """GSPMD path: whole stage loop traced under one ``jax.jit``; remaps are
     bit transposes + sharding constraints the compiler lowers to collectives.
-    Batches vmap the entire loop (single-array placement only)."""
+    The state is the lane-dense ``(2^(n-l), 2^l)`` array (see
+    :mod:`repro.sim.lanes`) whose rows are sharded over the mesh: shard
+    ``g * 2^R + r`` is the ``(g, r)``-th block of rows. Batches vmap the
+    entire loop (single-array placement only)."""
 
     name = "pjit"
 
@@ -521,16 +522,13 @@ class PjitBackend(Backend):
             if rsize != (1 << R):
                 raise BackendBuildError(
                     f"pjit mesh mismatch: ICI devices {rsize} != 2^R={1 << R}")
-            self.sharding = NamedSharding(
-                mesh,
-                P(
-                    tuple(self.global_axes) if G else None,
-                    tuple(self.regional_axes) if R else None,
-                    None,
-                ),
-            )
+            axes = ((tuple(self.global_axes) if G else ())
+                    + (tuple(self.regional_axes) if R else ()))
+            self.sharding = NamedSharding(mesh, P(axes or None, None))
         else:
             self.sharding = None
+        l = lanes.lane_bits(engine.L)
+        self.shape = (1 << (engine.n - l), 1 << l)
         dargs = (0,) if self.donate else ()
         self._fns = {
             True: jax.jit(partial(self._exec, apply_final=True), donate_argnums=dargs),
@@ -551,13 +549,11 @@ class PjitBackend(Backend):
         # binding of the circuit structure.
         eng = self.engine
         eng.xla_compiles += 1  # python side effect: runs at trace time only
-        G, R, L = eng.G, eng.R, eng.L
-        x = self._wsc(packed.reshape((1 << G, 1 << R) + (2,) * L))
-        x = eng.stage_loop(
+        x = self._wsc(packed.reshape(self.shape))
+        return eng.stage_loop(
             x, lambda v, prog: self._apply_ops(v, prog, consts),
             self._remap, apply_final,
         )
-        return x.reshape(1 << G, 1 << R, 1 << L)
 
     def _remap(self, x, slot, spec: RemapSpec):
         eng = self.engine
@@ -572,7 +568,8 @@ class PjitBackend(Backend):
             if eng.use_pallas and op.kind == "shm":
                 x = self._apply_shm_pallas(x, op, consts)
             else:
-                x = apply_op(x, op, eng.G, eng.R, eng.L, eng.dtype, consts)
+                x = apply_op(x, op, eng.G, eng.R, eng.L, eng.dtype, consts,
+                             eng.use_pallas)
         return x
 
     def _select_batched(self, m: Op, consts):
@@ -590,35 +587,28 @@ class PjitBackend(Backend):
 
     def _apply_shm_pallas(self, x, op: Op, consts):
         eng = self.engine
-        L = eng.L
         S = 1 << (eng.G + eng.R)
-        xf = x.reshape((S,) + (2,) * L)
         gate_list, scal = _shm_operands(op, lambda m: self._select_batched(m, consts))
         if not gate_list:
-            return (xf * scal.reshape((S,) + (1,) * L)).reshape(x.shape)
+            return lanes.apply_diag(x, scal[:, None], (), lanes.lane_bits(eng.L))
+        if S == 1:
+            return lanes.apply_shm_group(
+                x, [(b, m[0]) for b, m in gate_list], op.local_bits)
         bits_list = [b for b, _ in gate_list]
-        mats = [m for _, m in gate_list]
-        from ..kernels import ops as kops
-
+        xf = x.reshape((S, -1) + x.shape[1:])  # one row block per shard
         out = jax.vmap(
-            lambda v, *ms: kops.apply_shm_group(
+            lambda v, *ms: lanes.apply_shm_group(
                 v, list(zip(bits_list, ms)), op.local_bits
             )
-        )(xf, *mats)
+        )(xf, *[m for _, m in gate_list])
         return out.reshape(x.shape)
 
     # ---------------------------------------------------------------- api
     def prepare(self, psi0, batch: bool = False):
         eng = self.engine
-        shape = (1 << eng.G, 1 << eng.R, 1 << eng.L)
         if batch:
-            return jnp.asarray(psi0, dtype=eng.dtype).reshape((-1,) + shape)
-        if psi0 is None:
-            psi0 = jnp.zeros((2 ** eng.n,), dtype=eng.dtype).at[0].set(1.0)
-        packed = jnp.asarray(psi0, dtype=eng.dtype).reshape(shape)
-        if self.sharding is not None:
-            packed = jax.device_put(packed, self.sharding)
-        return packed
+            return jnp.asarray(psi0, dtype=eng.dtype).reshape((-1,) + self.shape)
+        return _place_state(psi0, self.shape, eng.dtype, self.sharding)
 
     def execute(self, state, apply_final: bool = True):
         return self._fns[apply_final](state, self.engine.consts)
@@ -660,7 +650,7 @@ class PjitBackend(Backend):
     def lower(self, psi_shape_only: bool = True):
         eng = self.engine
         shape = jax.ShapeDtypeStruct(
-            (1 << eng.G, 1 << eng.R, 1 << eng.L), eng.dtype,
+            self.shape, eng.dtype,
             **({"sharding": self.sharding} if self.sharding else {}),
         )
         cshapes = {u: jax.ShapeDtypeStruct(a.shape, a.dtype)
@@ -711,19 +701,20 @@ class ShardMapBackend(Backend):
             mesh=self.mesh,
             in_specs=(P(self.axis_names if nb else None), cspecs),
             out_specs=P(self.axis_names if nb else None),
-            check_rep=False,
+            check_vma=False,
         )
         return jax.jit(fn, donate_argnums=(0,))
 
     # ------------------------------------------------------------- traced
     def _device_fn(self, shard, consts, apply_final: bool = True):
         self.engine.xla_compiles += 1  # trace-time side effect
-        view = shard.reshape((2,) * self.engine.L)
-        view = self.engine.stage_loop(
-            view, lambda v, prog: self._apply_ops(v, prog, consts),
+        l = lanes.lane_bits(self.engine.L)
+        out = self.engine.stage_loop(
+            shard.reshape(-1, 1 << l),  # lane-dense (rows, lanes)
+            lambda v, prog: self._apply_ops(v, prog, consts),
             self._remap, apply_final,
         )
-        return view.reshape(-1)
+        return out.reshape(-1)
 
     def _remap(self, view, slot, spec: RemapSpec):
         return _apply_remap_plan(view, self._plans[slot], self.engine.L, self.axis_names)
@@ -756,16 +747,9 @@ class ShardMapBackend(Backend):
         if op.kind == "scalar":
             return view * Tsel
         if op.kind == "diag":
-            L = eng.L
-            shape = [2 if p in op.local_bits else 1 for p in range(L - 1, -1, -1)]
-            return view * Tsel.reshape(shape)
-        from .apply import apply_matrix
-
-        if eng.use_pallas and len(op.local_bits) >= 1:
-            from ..kernels import ops as kops
-
-            return kops.apply_fused_shard(view, Tsel, op.local_bits)
-        return apply_matrix(view, Tsel, list(op.local_bits))
+            return lanes.apply_diag(view, Tsel, op.local_bits,
+                                    lanes.lane_bits(eng.L))
+        return _apply_fused(view, Tsel, op.local_bits, eng.L, eng.use_pallas)
 
     def _apply_shm(self, view, op: Op, consts):
         """One shm group = one memory pass. On the Pallas path the whole
@@ -776,12 +760,10 @@ class ShardMapBackend(Backend):
             for m in op.gates:
                 view = self._apply_op(view, m, consts)
             return view
-        from ..kernels import ops as kops
-
         gate_list, scal = _shm_operands(op, lambda m: self._select(m, consts))
         if not gate_list:
             return view * scal
-        return kops.apply_shm_group(view, gate_list, op.local_bits)
+        return lanes.apply_shm_group(view, gate_list, op.local_bits)
 
     # ---------------------------------------------------------------- api
     def _fn(self, apply_final: bool):
@@ -795,9 +777,7 @@ class ShardMapBackend(Backend):
         eng = self.engine
         if batch:
             return jnp.asarray(psi0, dtype=eng.dtype).reshape(-1, 1 << eng.n)
-        if psi0 is None:
-            psi0 = jnp.zeros((2 ** eng.n,), dtype=eng.dtype).at[0].set(1.0)
-        return jax.device_put(jnp.asarray(psi0, dtype=eng.dtype), self.sharding)
+        return _place_state(psi0, (1 << eng.n,), eng.dtype, self.sharding)
 
     def execute(self, state, apply_final: bool = True):
         return self._fn(apply_final)(state, dict(self.engine.consts))

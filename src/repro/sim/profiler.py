@@ -46,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.cost_model import CostModel, DEFAULT_COST_MODEL
+from .lanes import apply_shm_group, apply_unitary, lane_bits
 
 # v2: adds the "disk" section (disk_gbps for the shard_store spill tier).
 # Files written by older versions miss fields the cost model now prices, so
@@ -97,9 +98,10 @@ def _time_us(fn: Callable, *args, repeats: int = 5, warmup: int = 1) -> float:
 
 
 def _rand_state(rng: np.random.Generator, L: int) -> jnp.ndarray:
+    """A random 2^L-amplitude shard in the engines' lane-dense layout."""
     x = rng.standard_normal(1 << L) + 1j * rng.standard_normal(1 << L)
     x /= np.linalg.norm(x)
-    return jnp.asarray(x.astype(np.complex64)).reshape((2,) * L)
+    return jnp.asarray(x.astype(np.complex64)).reshape(-1, 1 << lane_bits(L))
 
 
 def _rand_unitary(rng: np.random.Generator, k: int) -> np.ndarray:
@@ -138,41 +140,52 @@ def profile_pass(L: int, repeats: int = 5,
 
 def profile_fusion(L: int, kmax: Optional[int] = None, repeats: int = 3,
                    rng: Optional[np.random.Generator] = None) -> Dict:
-    """Fusion kernel cost per k: the Pallas MXU matmul the pjit/shardmap
-    backends run (``apply_fused_shard``), timed for k = 1..kmax on a 2^L
-    shard. The model says ``t(k) ~ launch + max(pass, mxu * 2^k)``, so the
-    per-2^k slope of the large-k tail estimates ``mxu_us_per_2k``."""
-    from ..kernels.ops import apply_fused_shard
+    """Fused-gate cost per k: the lane contraction the pjit/shardmap
+    backends run with Pallas (``lanes.apply_unitary`` through
+    ``kops.lane_matmul``), timed for k = 1..kmax on a 2^L shard, on the k
+    lowest bits (*lane* gates) and on the k highest (*routed* gates: the
+    targets are exchanged into the lanes and back). The model says ``t(k) ~
+    launch + max(pass, mxu * 2^k)``, so the per-2^k slope of the lane gates'
+    large-k tail estimates ``mxu_us_per_2k``. On a TPU a lane gate of any
+    k <= 7 is the same embedded 128x128 matmul and a routed gate also pays
+    its route, which that formula does not price; ``raw`` keeps both
+    series."""
+    from ..kernels.ops import lane_matmul
 
     rng = rng or np.random.default_rng(0)
     kmax = min(kmax or DEFAULT_COST_MODEL.max_fusion_qubits, L - 1)
     kmax = max(kmax, 1)
     view = _rand_state(rng, L)
     scale = 2.0 ** (REFERENCE_L - L)
+
+    def time_gate(u, bits) -> float:
+        fn = jax.jit(lambda v, m: apply_unitary(v, m, bits, L,
+                                                lane_matmul=lane_matmul))
+        return _time_us(fn, view, u, repeats=repeats)
+
     per_k: Dict[int, float] = {}
+    routed: Dict[int, float] = {}
     for k in range(1, kmax + 1):
         u = jnp.asarray(_rand_unitary(rng, k))
-        bits = tuple(range(k))
-        fn = jax.jit(lambda v, m, _b=bits: apply_fused_shard(v, m, _b))
-        per_k[k] = _time_us(fn, view, u, repeats=repeats)
+        per_k[k] = time_gate(u, tuple(range(k)))
+        routed[k] = time_gate(u, tuple(range(L - k, L)))
     # compute-bound tail: t28(k)/2^k flattens to mxu_us_per_2k
     tail = sorted(per_k)[len(per_k) // 2:]
     mxu = float(np.median([per_k[k] * scale / (1 << k) for k in tail]))
     return {
         "mxu_us_per_2k": mxu,
-        "raw": {"L": L, "per_k_us": {str(k): v for k, v in per_k.items()}},
+        "raw": {"L": L, "per_k_us": {str(k): v for k, v in per_k.items()},
+                "routed_per_k_us": {str(k): v for k, v in routed.items()}},
     }
 
 
 def profile_shm(L: int, repeats: int = 3,
                 rng: Optional[np.random.Generator] = None) -> Dict:
     """shm group cost vs member count and diagonal fraction: the Pallas
-    shared-memory kernel (``apply_shm_group``) with g member gates costs
+    shared-memory kernel (``lanes.apply_shm_group``) with g member gates costs
     ``alpha + sum_g cost(g)``; the incremental cost between g=1 and g=g2
     estimates the per-gate constants (``shm_gate_us`` non-diagonal via dense
     2-qubit unitaries, ``shm_diag_gate_us`` via 1-D diagonals)."""
-    from ..kernels.ops import apply_shm_group
-
     rng = rng or np.random.default_rng(0)
     a = min(4, L - 1)
     window = tuple(range(a))

@@ -48,13 +48,9 @@ from dataclasses import dataclass, replace
 from typing import Dict, Optional, Tuple, Union
 
 import numpy as np
+from ml_dtypes import bfloat16 as _bf16
 
 from . import faults
-
-try:  # ml_dtypes ships with jax; gate anyway so exact/int8 tiers survive
-    from ml_dtypes import bfloat16 as _bf16
-except ImportError:  # pragma: no cover - ml_dtypes is a jax dependency
-    _bf16 = None
 
 AT_REST_DTYPES = ("exact", "bf16", "int8")
 
@@ -226,8 +222,6 @@ def encode_shard(arr: np.ndarray, mode: str) -> Tuple[Encoded, float]:
         return Encoded("exact", (arr.copy(),), shape, dtype), 0.0
     f = _as_float_view(arr).astype(np.float32, copy=False)
     if mode == "bf16":
-        if _bf16 is None:  # pragma: no cover - ml_dtypes is a jax dependency
-            raise RuntimeError("bf16 at-rest tier needs ml_dtypes")
         q = f.astype(_bf16)
         dec = q.astype(np.float32)
         err = float(np.linalg.norm((f - dec).reshape(-1)))
@@ -390,9 +384,6 @@ class ShardStore:
     @staticmethod
     def _part_dtype(name: str):
         if name == "bfloat16":
-            if _bf16 is None:  # pragma: no cover - ml_dtypes ships with jax
-                raise faults.SpillIOError(
-                    "spilled bf16 shard but ml_dtypes is unavailable")
             return np.dtype(_bf16)
         return np.dtype(name)
 

@@ -60,7 +60,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.experimental.shard_map import shard_map
 from repro.train.compression import compressed_psum
 
-mesh = jax.make_mesh((4,), ('pod',))
+mesh = jax.make_mesh((4,), ('pod',), axis_types=(jax.sharding.AxisType.Auto,))
 rng = np.random.default_rng(0)
 x = jnp.asarray(rng.normal(size=(4, 16, 32)), jnp.float32)
 

@@ -110,7 +110,8 @@ from repro.core import generators as gen
 from repro.core.partition import partition
 from repro.sim.statevector import simulate, fidelity
 from repro.sim.executor import StagedExecutor
-mesh = jax.make_mesh((2, 2, 2), ('pod', 'data', 'model'))
+mesh = jax.make_mesh((2, 2, 2), ('pod', 'data', 'model'),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 c = gen.qft(9)
 plan = partition(c, 6, 2, 1)
 f = fidelity(StagedExecutor(c, plan, mesh=mesh).run(), simulate(c))

@@ -12,10 +12,11 @@ except ImportError:  # clean env: deterministic fallback sweep
 
 from repro.core import gates as G
 from repro.kernels.fusion import fused_matmul
-from repro.kernels.ops import apply_fused_shard, apply_shm_shard
+from repro.kernels.ops import lane_matmul
 from repro.kernels.ref import fused_matmul_ref, shm_apply_ref
 from repro.kernels.shm import shm_apply
 from repro.sim.apply import apply_matrix
+from repro.sim.lanes import apply_shm_group, apply_unitary
 
 
 def _rand_unitary(rng, k):
@@ -24,8 +25,8 @@ def _rand_unitary(rng, k):
 
 
 @pytest.mark.parametrize("k", [1, 2, 3, 5, 7])
-@pytest.mark.parametrize("karatsuba", [False, True])
-def test_fused_matmul_sweep(k, karatsuba):
+@pytest.mark.parametrize("block_m", [32, 128])
+def test_fused_matmul_sweep(k, block_m):
     rng = np.random.default_rng(k)
     M, K = 128, 2**k
     sre = rng.normal(size=(M, K)).astype(np.float32)
@@ -34,7 +35,7 @@ def test_fused_matmul_sweep(k, karatsuba):
     ure, uim = np.real(u).astype(np.float32), np.imag(u).astype(np.float32)
     o_re, o_im = fused_matmul(
         jnp.array(sre), jnp.array(sim), jnp.array(ure), jnp.array(uim),
-        block_m=32, karatsuba=karatsuba, interpret=True,
+        block_m=block_m, interpret=True,
     )
     r_re, r_im = fused_matmul_ref(jnp.array(sre), jnp.array(sim),
                                   jnp.array(ure), jnp.array(uim))
@@ -89,14 +90,17 @@ def test_shm_kernel_vs_ref():
 @given(seed=st.integers(0, 1000))
 def test_apply_fused_shard_property(seed):
     rng = np.random.default_rng(seed)
-    L, k = 7, 3
+    L, k = 15, 3  # targets in the lanes or routed in from the rows
     psi = (rng.normal(size=2**L) + 1j * rng.normal(size=2**L)).astype(np.complex64)
     bits = sorted(rng.choice(L, size=k, replace=False).tolist())
     u = _rand_unitary(rng, k).astype(np.complex64)
     view = jnp.asarray(psi).reshape((2,) * L)
-    out = apply_fused_shard(view, jnp.asarray(u), bits)
+    # the engine's Pallas path: targets routed into the lanes, MXU kernel
+    out = apply_unitary(jnp.asarray(psi).reshape(-1, 1 << min(L, 7)),
+                        jnp.asarray(u), bits, L, lane_matmul=lane_matmul)
     ref = apply_matrix(view, jnp.asarray(u), bits)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4)
+    np.testing.assert_allclose(np.asarray(out).reshape(-1),
+                               np.asarray(ref).reshape(-1), atol=1e-4)
 
 
 def test_apply_shm_shard_matches_sequential():
@@ -105,9 +109,11 @@ def test_apply_shm_shard_matches_sequential():
     psi = (rng.normal(size=2**L) + 1j * rng.normal(size=2**L)).astype(np.complex64)
     gates = [((0,), G.H), ((1, 2), G.CX), ((3,), G.gate_matrix("rz", [0.3]))]
     view = jnp.asarray(psi).reshape((2,) * L)
-    out = apply_shm_shard(view, gates, a)
+    out = apply_shm_group(jnp.asarray(psi).reshape(-1, 1 << min(L, 7)),
+                          [(b, jnp.asarray(m)) for b, m in gates], range(a))
     ref = view
     for bits, mat in gates:
         ref = apply_matrix(ref, jnp.asarray(np.asarray(mat).astype(np.complex64)),
                            list(bits))
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4)
+    np.testing.assert_allclose(np.asarray(out).reshape(-1),
+                               np.asarray(ref).reshape(-1), atol=1e-4)

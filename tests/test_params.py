@@ -216,7 +216,8 @@ def test_run_sweep_pjit_with_mesh_falls_back():
     n = 6
     sym = _ansatz(n)
     plan = partition(sym, 4, 2, 0)
-    mesh = jax.make_mesh((1, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((1, 2, 2), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
     eng = ExecutionEngine(sym, plan, backend="pjit", mesh=mesh)
     assert not eng.backend.supports_fused_sweep()
     batch = np.stack([_vals(n, s) for s in (20, 21)])
