@@ -25,6 +25,7 @@ from .kernelization import (
     validate_kernelization,
 )
 from .staging import Stage, StagingResult, stage as run_stage, validate_staging
+from ..sim.trace import span
 
 
 @dataclass
@@ -135,6 +136,7 @@ _KERNELIZERS = {
 }
 
 
+@span("plan")
 def partition(
     circuit: Circuit,
     L: int,

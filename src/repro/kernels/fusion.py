@@ -69,5 +69,6 @@ def fused_matmul(
             out_specs=[state_spec, state_spec],
             out_shape=out_shape,
             interpret=interpret,
+            name="fused_lanes",
         )(sre, sim, ure, uim)
     )

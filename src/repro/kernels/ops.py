@@ -51,7 +51,13 @@ def _interpret() -> bool:
 
 
 def _to_planar(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    return jnp.real(x).astype(jnp.float32), jnp.imag(x).astype(jnp.float32)
+    with jax.named_scope("planar"):
+        return jnp.real(x).astype(jnp.float32), jnp.imag(x).astype(jnp.float32)
+
+
+def _from_planar(re: jnp.ndarray, im: jnp.ndarray, dtype) -> jnp.ndarray:
+    with jax.named_scope("planar"):
+        return jax.lax.complex(re, im).astype(dtype)
 
 
 def fused_block_m(m: int, k: int = 128) -> int:
@@ -77,7 +83,7 @@ def lane_matmul(x: jnp.ndarray, E, l: int) -> jnp.ndarray:
         ore, oim = fused_matmul(sre, sim, ure, uim,
                                 block_m=fused_block_m(rows, cols),
                                 interpret=interp)
-        return jax.lax.complex(ore, oim).astype(x.dtype)
+        return _from_planar(ore, oim, x.dtype)
 
     if E.ndim == 2:
         return one(x, E).reshape(x.shape)
@@ -97,4 +103,4 @@ def shm_kernel(x: jnp.ndarray,
     interp = _interpret()
     sre, sim = _to_planar(x)
     ore, oim = shm_apply(sre, sim, gates, window_bits, interpret=interp)
-    return jax.lax.complex(ore, oim).astype(x.dtype)
+    return _from_planar(ore, oim, x.dtype)

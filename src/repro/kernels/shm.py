@@ -202,7 +202,9 @@ def shm_apply(
     # the tiles' row period: whole windows and at least one sublane tile,
     # never taller than a block (a chosen block is at least this tall)
     p_rows = max(period, min(8, block_m or m))
-    program, mats, tiles = _lower_members(gates, c, p_rows)
+    # built from the op tensors inside the traced program, so in every run
+    with jax.named_scope("operands"):
+        program, mats, tiles = _lower_members(gates, c, p_rows)
     operands = [x for x in (mats, tiles) if x is not None]
     fixed = 2 * sum(x.size * x.dtype.itemsize for x in operands)
     bm = min(block_m or choose_block_m(m, 4 * C, planes=BLOCK_PLANES,
@@ -223,6 +225,7 @@ def shm_apply(
         out_specs=[spec, spec],
         out_shape=[jax.ShapeDtypeStruct((m, C), jnp.float32)] * 2,
         interpret=interpret,
+        name="shm_group",
         **params,
     )(sre, sim, *operands)
     return ore.reshape(shape), oim.reshape(shape)

@@ -213,8 +213,9 @@ class WarmPool:
             if getattr(e, "provenance", {}).get("degraded")
             or getattr(e, "provenance", {}).get("integrity_retries")
         ]
-        # per-engine wall-time aggregates + autotune outcomes, keyed by
-        # truncated CircuitKey digest (matches requests_by_key)
+        # per-engine span aggregates (host time to dispatch, by span path)
+        # + autotune outcomes, keyed by truncated CircuitKey digest (matches
+        # requests_by_key)
         with self.cache._lock:
             entries = list(self.cache._d.items())
         out["engine_timings"] = {
@@ -495,13 +496,12 @@ class SimulationService:
         }
         snap["retry_after_s"] = self.retry_after()
         # profile-guided planning provenance: which cost model this process
-        # plans with, tuning outcomes, and the production observation ring
+        # plans with, and tuning outcomes
         from ..core.autotune import tuned_outcomes
-        from ..sim.profiler import observation_summary, resolve_calibration
+        from ..sim.profiler import resolve_calibration
 
         snap["calibration"] = resolve_calibration()[1]
         snap["autotune"] = tuned_outcomes()
-        snap["observations"] = observation_summary()
         from ..sim import faults
 
         plan = faults.active()

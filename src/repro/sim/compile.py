@@ -29,6 +29,7 @@ from ..core.cost_model import FUSION, SHM
 from ..core.gates import UnboundParameterError
 from ..core.partition import SimulationPlan
 from . import faults
+from .trace import span
 from .apply import embed_matrix, gather_bits, scatter_bits, specialize_gate
 
 INSULAR_KIND = 2  # kernel.kind for zero-footprint bookkeeping kernels
@@ -214,6 +215,7 @@ def _remap_spec(
     return RemapSpec(src_bit_of=src, flip_bits=flip_bits)
 
 
+@span("compile_plan")
 def compile_plan(
     circuit: Circuit, plan: SimulationPlan, dtype=np.complex64,
     peephole: bool = True, struct_cache: Optional[Dict] = None,
@@ -266,7 +268,8 @@ def compile_plan(
                     members.extend(_build_fused(circuit, [gid], None, phys_of, L,
                                                 flip_before, dtype, struct_cache))
                 if peephole:
-                    members = _peephole(members, dtype)
+                    with span("peephole"):
+                        members = _peephole(members, dtype)
                 if len(members) <= 1 or all(m.kind == "scalar" for m in members):
                     ops.extend(members)  # degenerate group: no kernel needed
                 else:
@@ -287,7 +290,8 @@ def compile_plan(
                     if op is not None:
                         ops.append(op)
         if peephole:
-            ops = _peephole(ops, dtype)
+            with span("peephole"):
+                ops = _peephole(ops, dtype)
 
         # --- remap to next stage --------------------------------------------
         if si + 1 < len(plan.stages):

@@ -75,7 +75,7 @@ from .compile import (
     bind_tensors_sweep,
     compile_plan,
 )
-from . import lanes
+from . import lanes, trace
 from .shard_store import ShardStore, StorageConfig
 
 
@@ -98,6 +98,15 @@ def _dep_index(op: Op, G: int, R: int, L: int) -> Optional[jnp.ndarray]:
             bit = (r_iota >> (p - L)) & 1
         idx = idx | (bit << j)
     return idx
+
+
+#: The ``jax.named_scope`` of each stage op kind on the pjit path: the device
+#: trace carries it in each op's metadata (``op_name``), so device time can be
+#: read per kind. Inside them, :mod:`repro.sim.lanes` opens ``route``,
+#: :mod:`repro.kernels.ops` ``planar``, and the building of the kernels'
+#: operands from the op tensors ``operands``; remaps and the logical-order
+#: reshape run under ``remap``.
+OP_SCOPES = {"shm": "shm", "fused": "fused", "diag": "diag", "scalar": "diag"}
 
 
 def apply_op(
@@ -433,6 +442,14 @@ def _place_state(psi0, shape, dtype, sharding):
     return jax.device_put(x, sharding) if sharding is not None else jnp.asarray(x)
 
 
+@partial(jax.jit, static_argnames="batch")
+def _to_logical(out, batch: bool):
+    """The flat logical order of a lane-dense result (a copy on a TPU,
+    which tiles the last two dimensions)."""
+    with jax.named_scope("remap"):
+        return out.reshape(out.shape[0], -1) if batch else out.reshape(-1)
+
+
 class Backend:
     """One execution substrate under the engine's stage loop.
 
@@ -489,7 +506,7 @@ class Backend:
         return jnp.stack(outs)
 
     def extract(self, out, batch: bool = False):
-        return out.reshape(out.shape[0], -1) if batch else out.reshape(-1)
+        return _to_logical(out, batch)
 
 
 class PjitBackend(Backend):
@@ -557,7 +574,8 @@ class PjitBackend(Backend):
 
     def _remap(self, x, slot, spec: RemapSpec):
         eng = self.engine
-        return self._wsc(apply_remap(x, spec, eng.n, eng.G, eng.R, eng.L))
+        with jax.named_scope("remap"):
+            return self._wsc(apply_remap(x, spec, eng.n, eng.G, eng.R, eng.L))
 
     def _apply_ops(self, x, prog: StageProgram, consts):
         eng = self.engine
@@ -565,11 +583,12 @@ class PjitBackend(Backend):
         # fuse; with use_pallas an shm group runs as ONE pallas_call per
         # shard, vmapped over the packed shard axes)
         for op in prog.ops:
-            if eng.use_pallas and op.kind == "shm":
-                x = self._apply_shm_pallas(x, op, consts)
-            else:
-                x = apply_op(x, op, eng.G, eng.R, eng.L, eng.dtype, consts,
-                             eng.use_pallas)
+            with jax.named_scope(OP_SCOPES[op.kind]):
+                if eng.use_pallas and op.kind == "shm":
+                    x = self._apply_shm_pallas(x, op, consts)
+                else:
+                    x = apply_op(x, op, eng.G, eng.R, eng.L, eng.dtype, consts,
+                                 eng.use_pallas)
         return x
 
     def _select_batched(self, m: Op, consts):
@@ -909,13 +928,18 @@ class HostOffloadBackend(Backend):
 
     # -------------------------------------------------------------- eager
     def _stream_stage(self, state, prog: StageProgram):
-        if isinstance(state, ShardStore):
-            return self._stream_stage_store(state, prog)
-        eng = self.engine
-        L = eng.L
         if faults._ACTIVE is not None:
             faults.maybe_inject("slow_stage", site="offload.stage")
-        t_stage = time.perf_counter()
+        # eager backend => each stage's host time is directly observable (the
+        # traced backends can only time whole executables)
+        with trace.span("offload_stage", self.engine.timings):
+            if isinstance(state, ShardStore):
+                return self._stream_stage_store(state, prog)
+            return self._stream_stage_array(state, prog)
+
+    def _stream_stage_array(self, state, prog: StageProgram):
+        eng = self.engine
+        L = eng.L
         batched = state.ndim == 2
         fn = self.shard_fn(_op_sig(prog.ops), batched=batched,
                            sweep=self._sweep_consts is not None)
@@ -944,9 +968,6 @@ class HostOffloadBackend(Backend):
         if pending is not None:
             ps, pout = pending
             state[..., ps << L:(ps + 1) << L] = np.asarray(pout)
-        # eager backend => per-stage wall time is directly observable (the
-        # traced backends can only time whole executables)
-        eng._record_time("offload_stage", (time.perf_counter() - t_stage) * 1e6)
         return state
 
     def _stream_stage_store(self, store: ShardStore, prog: StageProgram):
@@ -956,9 +977,6 @@ class HostOffloadBackend(Backend):
         s-1's result re-encodes back into the store while s+1 is in flight —
         the spill tier hides behind the same ``overlap_ratio``."""
         eng = self.engine
-        if faults._ACTIVE is not None:
-            faults.maybe_inject("slow_stage", site="offload.stage")
-        t_stage = time.perf_counter()
         batched = store.ndim == 2
         fn = self.shard_fn(_op_sig(prog.ops), batched=batched,
                            sweep=self._sweep_consts is not None)
@@ -986,7 +1004,6 @@ class HostOffloadBackend(Backend):
         if pending is not None:
             ps, pout = pending
             store.put(ps, np.asarray(pout))
-        eng._record_time("offload_stage", (time.perf_counter() - t_stage) * 1e6)
         return store
 
     def _remap(self, state, slot, spec: RemapSpec):
@@ -1221,6 +1238,7 @@ class ExecutionEngine:
     """Backend-agnostic staged executor: one stage loop, one constant
     registry, one public API — the backend only supplies the substrate."""
 
+    @trace.span("build")
     def __init__(
         self,
         circuit: Circuit,
@@ -1264,12 +1282,12 @@ class ExecutionEngine:
         self.bind_count = 0
         self.xla_compiles = 0  # traces of backend executables (rebinding
         # must never increment this after warmup)
-        # per-entry-point wall-time aggregates (count/total/last/max in us),
-        # fed by _record_time on every run*/offload-stage; every record also
-        # lands in the profiler observation ring so production traffic keeps
-        # contributing calibration sanity-check data. Surfaced by
-        # timing_snapshot() -> serve stats / bench JSON.
-        self.timings: Dict[str, Dict[str, float]] = {}
+        # host-time aggregates (count/total/max, seconds) of the spans of
+        # every run* and offload stage, by span path (``engine.run/execute``),
+        # fed only by :func:`repro.sim.trace.span`. Host time to dispatch:
+        # the device may still be running when a span closes. Surfaced by
+        # timing_snapshot() -> the serve stats' ``engine_timings``.
+        self.timings: trace.Table = {}
         self._struct_cache: Dict = {}  # binding-independent build artifacts
         # shared by every bind_tensors pass (see compile_plan struct_cache)
         # op-tensor registry, keyed by stable ``Op.uid``: one device array per
@@ -1277,17 +1295,19 @@ class ExecutionEngine:
         # baked-in constant) so one XLA executable serves every binding.
         # Built eagerly — inside a jit trace the dtype cast would leak tracers.
         self.consts: Dict[int, jnp.ndarray] = {}
-        for prog in self.cc.programs:
-            for op in prog.ops:
-                for o in (op,) + op.gates:
-                    if o.tensor.size:
-                        self.consts[o.uid] = jnp.asarray(o.tensor, dtype=self.dtype)
+        with trace.span("consts"):
+            for prog in self.cc.programs:
+                for op in prog.ops:
+                    for o in (op,) + op.gates:
+                        if o.tensor.size:
+                            self.consts[o.uid] = jnp.asarray(o.tensor, dtype=self.dtype)
         if isinstance(backend, str):
             backend = BACKENDS[backend](**backend_kw)
         elif backend_kw:
             raise TypeError("backend_kw only apply when backend is given by name")
         self.backend = backend
-        backend.setup(self)
+        with trace.span("backend"):
+            backend.setup(self)
         self.provenance["backend"] = backend.name
         self.provenance["use_pallas"] = use_pallas
 
@@ -1344,29 +1364,12 @@ class ExecutionEngine:
         return [dict(zip(names, row)) for row in arr]
 
     # --------------------------------------------------------------- timing
-    def _record_time(self, name: str, wall_us: float) -> None:
-        t = self.timings.setdefault(
-            name, {"count": 0, "total_us": 0.0, "last_us": 0.0, "max_us": 0.0})
-        t["count"] += 1
-        t["total_us"] += wall_us
-        t["last_us"] = wall_us
-        t["max_us"] = max(t["max_us"], wall_us)
-        from . import profiler
-
-        profiler.record_observation(
-            name, wall_us=wall_us, backend=self.backend.name,
-            n=self.n, L=self.L, n_stages=len(self.cc.programs))
-
     def timing_snapshot(self) -> Dict[str, Dict[str, float]]:
-        """JSON-able copy of the per-entry-point wall-time aggregates, with
-        derived means — the serve stats and bench ``--json`` payloads embed
-        this."""
-        snap: Dict[str, Dict[str, float]] = {}
-        for k, t in self.timings.items():
-            d = dict(t)
-            d["mean_us"] = d["total_us"] / max(d["count"], 1)
-            snap[k] = d
-        return snap
+        """JSON-able copy of the engine's span aggregates (count, total,
+        max and mean host seconds, by span path), which the serve stats
+        embed. Host time to dispatch: the device may still be running when
+        a run's span closes."""
+        return trace.snapshot(self.timings)
 
     # ------------------------------------------------------------- shared
     @property
@@ -1463,10 +1466,13 @@ class ExecutionEngine:
             self._require_bound()
             if faults._ACTIVE is not None:
                 faults.maybe_inject("slow_stage", site="engine.run")
-            t0 = time.perf_counter()
-            state = self.backend.prepare(psi0)
-            out = self.backend.extract(self.backend.execute(state, True))
-            self._record_time("run", (time.perf_counter() - t0) * 1e6)
+            with trace.span("engine.run", self.timings):
+                with trace.span("prepare", self.timings):
+                    state = self.backend.prepare(psi0)
+                with trace.span("execute", self.timings):
+                    out = self.backend.execute(state, True)
+                with trace.span("extract", self.timings):
+                    out = self.backend.extract(out)
         if faults._ACTIVE is not None and faults.should_corrupt("engine.run"):
             out = self._poison(out)
         if verify:
@@ -1485,9 +1491,8 @@ class ExecutionEngine:
             self._require_bound()
             if faults._ACTIVE is not None:
                 faults.maybe_inject("slow_stage", site="engine.run")
-            t0 = time.perf_counter()
-            out = self.backend.execute(self.backend.prepare(psi0), False)
-            self._record_time("run_packed", (time.perf_counter() - t0) * 1e6)
+            with trace.span("engine.run_packed", self.timings):
+                out = self.backend.execute(self.backend.prepare(psi0), False)
         if faults._ACTIVE is not None and faults.should_corrupt("engine.run"):
             out = self._poison(out)
         if verify:
@@ -1501,11 +1506,10 @@ class ExecutionEngine:
         :func:`repro.sim.measure.measure_batch`)."""
         with self.lock:
             self._require_bound()
-            t0 = time.perf_counter()
-            states = self.backend.prepare(psi0s, batch=True)
-            out = self.backend.execute_batch(states, apply_final)
-            out = self.backend.extract(out, batch=True) if apply_final else out
-            self._record_time("run_batch", (time.perf_counter() - t0) * 1e6)
+            with trace.span("engine.run_batch", self.timings):
+                states = self.backend.prepare(psi0s, batch=True)
+                out = self.backend.execute_batch(states, apply_final)
+                out = self.backend.extract(out, batch=True) if apply_final else out
         return out
 
     def run_sweep(self, psi0, params_batch, apply_final: bool = True,
@@ -1525,12 +1529,11 @@ class ExecutionEngine:
         points = self._sweep_points(params_batch)
         if not points:
             raise ValueError("empty params_batch")
-        t0 = time.perf_counter()
         # the fused path parks per-sweep tensor tables on the backend
         # (``_sweep_consts``/``_sweep_slices``): without the lock two
         # concurrent sweeps interleave on that shared state and one of them
         # silently reads the other's (or the placeholder) tensors
-        with self.lock:
+        with trace.span("engine.run_sweep", self.timings), self.lock:
             if self.backend.supports_fused_sweep():
                 if faults._ACTIVE is not None:
                     faults.maybe_inject("slow_stage", site="engine.run_sweep")
@@ -1555,7 +1558,6 @@ class ExecutionEngine:
                     out = np.stack(outs)
                 else:
                     out = jnp.stack(outs)
-            self._record_time("run_sweep", (time.perf_counter() - t0) * 1e6)
         if faults._ACTIVE is not None and faults.should_corrupt("engine.run_sweep"):
             out = self._poison_row(out, len(points))
         if verify:
@@ -1977,6 +1979,7 @@ def _plan_resilient(circuit, L, R, G, *, staging_method, kernelize_method,
             km = "greedy"
 
 
+@trace.span("build")
 def build_engine(
     circuit: Circuit,
     plan: SimulationPlan,

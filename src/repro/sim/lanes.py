@@ -279,7 +279,9 @@ def apply_unitary(x: jnp.ndarray, U, bits: Sequence[int], L: int,
     contract = lane_matmul or (
         lambda y, E, l_: lane_contract(y, jnp.swapaxes(E, -1, -2), l_))
     if all(b < l for b in bits):
-        return contract(x, embed(U, bits, l), l)
+        with jax.named_scope("operands"):
+            E = embed(U, bits, l)
+        return contract(x, E, l)
     if all(b >= l for b in bits) and len(bits) <= 2 and U.ndim == 2:
         return _row_combo(x, U, bits, l)
     cur = list(range(_nbits(x)))
@@ -288,10 +290,14 @@ def apply_unitary(x: jnp.ndarray, U, bits: Sequence[int], L: int,
     order = cur[:L] if any(b < l for b in bits) else cur[l:L] + cur[:l]
     fill = [b for b in order if b not in bits]
     arrs = plan_lanes(cur, sorted(bits + fill[: l - len(bits)]), L=L)
-    y = run_steps(x, arrs, l)
+    with jax.named_scope("route"):
+        y = run_steps(x, arrs, l)
     pos = [arrs[-1].index(b) for b in bits]
-    y = contract(y, embed(U, pos, l), l)
-    return run_steps(y, arrs, l, reverse=True)
+    with jax.named_scope("operands"):
+        E = embed(U, pos, l)
+    y = contract(y, E, l)
+    with jax.named_scope("route"):
+        return run_steps(y, arrs, l, reverse=True)
 
 
 def apply_diag(x: jnp.ndarray, d, bits: Sequence[int], l: int) -> jnp.ndarray:
@@ -363,6 +369,8 @@ def apply_shm_group(x: jnp.ndarray, gates, window: Sequence[int]) -> jnp.ndarray
         arrs = arrs + [gathered]
     slot = {b: q for q, b in enumerate(arrs[-1])}
     rel = [(tuple(slot[b] for b in bits), mat) for bits, mat in gates]
-    y = run_steps(x, arrs, l).reshape(-1, 1 << l)
+    with jax.named_scope("route"):
+        y = run_steps(x, arrs, l).reshape(-1, 1 << l)
     out = kops.shm_kernel(y, rel, l + len(in_rows)).reshape(x.shape)
-    return run_steps(out, arrs, l, reverse=True)
+    with jax.named_scope("route"):
+        return run_steps(out, arrs, l, reverse=True)

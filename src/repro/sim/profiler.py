@@ -38,7 +38,6 @@ import json
 import math
 import os
 import time
-from collections import deque
 from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -429,40 +428,6 @@ def resolve_calibration(path: Optional[str] = None, *,
 def clear_resolved_cache() -> None:
     """Drop the per-process resolution memo (tests; post-recalibration)."""
     _RESOLVED.clear()
-
-
-# ======================================================================
-# Production observation sink
-# ======================================================================
-
-#: Bounded ring of lightweight runtime observations: every engine run (and
-#: every offload stage) appends one record so production traffic keeps
-#: contributing data the next calibration can sanity-check against.
-OBSERVATIONS: "deque[Dict]" = deque(maxlen=4096)
-
-
-def record_observation(kind: str, **data) -> None:
-    OBSERVATIONS.append({"kind": kind, **data})
-
-
-def observation_summary() -> Dict[str, Dict]:
-    """Per-kind aggregate of the observation ring: count / total / mean /
-    max wall-microseconds. Surfaced by the serve metrics snapshot."""
-    agg: Dict[str, Dict] = {}
-    for ob in list(OBSERVATIONS):
-        a = agg.setdefault(ob["kind"], {"count": 0, "total_us": 0.0,
-                                        "max_us": 0.0})
-        us = float(ob.get("wall_us", 0.0))
-        a["count"] += 1
-        a["total_us"] += us
-        a["max_us"] = max(a["max_us"], us)
-    for a in agg.values():
-        a["mean_us"] = a["total_us"] / max(a["count"], 1)
-    return agg
-
-
-def clear_observations() -> None:
-    OBSERVATIONS.clear()
 
 
 # ======================================================================

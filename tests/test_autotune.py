@@ -270,21 +270,14 @@ class TestProfiler:
         plan = partition(qft(6), 4, 2, 0, cost_model=cm)
         assert plan.n_stages >= 1
 
-    def test_observations_ring(self):
-        profiler.clear_observations()
-        eng = engine_for(qft(6), 4, 2, 0, cache=None)
-        eng.run()
-        summary = profiler.observation_summary()
-        assert summary["run"]["count"] >= 1
-        assert summary["run"]["mean_us"] > 0
-
     def test_engine_timings_recorded(self):
         eng = engine_for(qft(6), 4, 2, 0, backend="offload", cache=None)
         eng.run()
         snap = eng.timing_snapshot()
-        assert snap["run"]["count"] == 1
+        assert snap["engine.run"]["count"] == 1
+        assert snap["engine.run"]["mean_s"] > 0
         # eager offload backend records each stage individually
-        assert snap["offload_stage"]["count"] == eng.plan.n_stages
+        assert snap["engine.run/execute/offload_stage"]["count"] == eng.plan.n_stages
 
 
 # ======================================================================
@@ -396,5 +389,4 @@ class TestServingSurface:
         assert stats["calibration"]["source"] in (
             "analytic", "calibrated", "disabled", "mismatch", "error")
         assert isinstance(stats["autotune"], list)
-        assert stats["observations"]["run"]["count"] >= 1
         assert stats["warm_pool"]["engine_timings"]

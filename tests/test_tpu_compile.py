@@ -53,8 +53,10 @@ def test_fused_matmul_compiles_at_chosen_block(one_chip):
 
     state = _sds((m, k), jnp.float32, one_chip)
     gate = _sds((k, k), jnp.float32, one_chip)
-    compiled = jax.jit(f).lower(state, state, gate, gate).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(f).lower(state, state, gate, gate).compile().as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's name, which the device trace carries
+    assert "%fused_lanes" in text
 
 
 def _shm_members(a: int):
@@ -85,8 +87,9 @@ def test_shm_kernel_compiles(one_chip, a):
         return shm_apply(sre, sim, gates, a, interpret=False)
 
     state = _sds((rows, 128), jnp.float32, one_chip)
-    compiled = jax.jit(f).lower(state, state).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(f).lower(state, state).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "%shm_group" in text
 
 
 def test_pjit_qft28_fits_hbm(one_chip, monkeypatch):
