@@ -30,6 +30,11 @@ PRECISION = jax.lax.Precision.HIGHEST
 # headroom); a kernel that needs more asks for it (:func:`vmem_limit`).
 VMEM_BUDGET_BYTES = 12 << 20
 
+# A TPU v5e core holds 128 MiB of VMEM (a v6e core too). A kernel whose
+# grid-constant operands outgrow the budget above may ask for VMEM up to
+# this cap; the last 16 MiB stay the compiler's.
+VMEM_CAP_BYTES = (128 - 16) << 20
+
 
 def lane_bits(L: int) -> int:
     return min(LANE_BITS, L)

@@ -8,14 +8,14 @@ a TPU, refused anywhere else. Nothing is decided at import time.
 
 from __future__ import annotations
 
-from typing import Sequence, Tuple
+from typing import List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from .fusion import fused_matmul
 from .layout import choose_block_m
-from .shm import shm_apply
+from .shm import ShmBlock, shm_apply
 
 # Trace-time pallas_call emission counters: each wrapper bumps its counter
 # once per call site traced, so after `jit`-tracing an executor the counts
@@ -23,14 +23,32 @@ from .shm import shm_apply
 # compiled program. "interpreted" counts call sites traced in interpret mode.
 KERNEL_CALLS = {"fused": 0, "shm": 0, "interpreted": 0}
 
+# Trace-time record of each shm call site's VMEM layout
+# (:class:`repro.kernels.shm.ShmBlock`), in trace order.
+SHM_BLOCKS: List[ShmBlock] = []
+
 
 def reset_kernel_counters() -> None:
     for k in KERNEL_CALLS:
         KERNEL_CALLS[k] = 0
+    SHM_BLOCKS.clear()
 
 
 def kernel_call_counts() -> dict:
     return dict(KERNEL_CALLS)
+
+
+def shm_block_stats() -> dict:
+    """The traced shm calls' block rows, chunk rows and operand bytes, and
+    how many got another block than sizing operands and blocks against one
+    budget gives (``resized``)."""
+    return {
+        "calls": len(SHM_BLOCKS),
+        "resized": sum(b.block != b.shared_block for b in SHM_BLOCKS),
+        "blocks": [b.block for b in SHM_BLOCKS],
+        "chunks": [b.chunk for b in SHM_BLOCKS],
+        "operand_bytes": [b.operand_bytes for b in SHM_BLOCKS],
+    }
 
 
 def interpret_mode() -> bool:
@@ -102,5 +120,6 @@ def shm_kernel(x: jnp.ndarray,
     KERNEL_CALLS["shm"] += 1
     interp = _interpret()
     sre, sim = _to_planar(x)
-    ore, oim = shm_apply(sre, sim, gates, window_bits, interpret=interp)
+    ore, oim = shm_apply(sre, sim, gates, window_bits, interpret=interp,
+                         record=SHM_BLOCKS.append)
     return _from_planar(ore, oim, x.dtype)

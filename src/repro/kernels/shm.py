@@ -19,6 +19,9 @@ model):
 * a diagonal member is one elementwise multiply by its periodic tile; runs
   of diagonals are pre-multiplied into one tile.
 
+The block is sized from its own buffers and the operands get VMEM of their
+own; a tall block runs the members on row chunks (:func:`shm_block`).
+
 Gate *structure* (bits, kinds) is static; gate *values* are kernel operands
 built from the (possibly traced, dep-selected) member tensors outside the
 kernel, so one compiled kernel serves every binding.
@@ -26,7 +29,7 @@ kernel, so one compiled kernel serves every binding.
 
 from __future__ import annotations
 
-from typing import List, Sequence, Tuple
+from typing import Callable, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,14 +37,62 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .layout import (PRECISION, ascending, choose_block_m, embed, gather_bits,
-                     vmem_limit)
+from .layout import (PRECISION, VMEM_CAP_BYTES, ascending, choose_block_m,
+                     embed, gather_bits, vmem_limit)
 
 LANES = 128
 
 # block-sized VMEM buffers of one shm kernel: the two input and two output
 # planes, double buffered, and the body's block-sized temporaries
 BLOCK_PLANES = 16
+
+# VMEM copies of the lowered lane matrices and tiles: their block index never
+# changes, so the pipeline holds one buffer of each (``pl.Buffered(1)``)
+OPERAND_BUFFERS = 1
+
+# the fewest rows the member program runs on at a time: each lane product
+# loads a 128 x 128 matrix into the MXU, and a shorter chunk streams too few
+# rows through it per load
+MIN_CHUNK_ROWS = 128
+
+
+class ShmBlock(NamedTuple):
+    """How one shm call is laid out in VMEM (rows of ``C`` lanes)."""
+    block: int          # rows of a grid step
+    chunk: int          # rows the member program runs on at a time
+    operand_bytes: int  # lowered lane matrices and tiles
+    shared_block: int   # the block when operands and blocks share the budget
+    vmem_limit: int     # ``vmem_limit_bytes`` asked for (0: the default)
+
+
+def shm_block(m: int, C: int, period: int, operand_bytes: int,
+              block_m: int = 0) -> ShmBlock:
+    """Size an shm call over ``[m, C]`` planes whose window repeats every
+    ``period`` rows and whose lowered operands take ``operand_bytes``.
+
+    The block is the largest whose block-sized buffers fit the scoped VMEM
+    budget; the grid-constant operands get VMEM of their own on top, up to
+    the chip's cap (if they would not fit beside it, the block is sized
+    against both together, as the chunk is). The body's code grows with
+    the rows it runs on times its member program, and the operands' bytes
+    grow with the program, so the program runs on chunks of the block that
+    operands and blocks would share the budget for, never fewer than
+    ``MIN_CHUNK_ROWS``; where that is the whole block, unchunked. An
+    explicit ``block_m`` is the block, run unchunked."""
+    row = 4 * C
+    min_rows = max(8, period)
+    limit = lambda bm: vmem_limit(  # noqa: E731
+        BLOCK_PLANES * bm * row + OPERAND_BUFFERS * operand_bytes)
+    shared = choose_block_m(m, row, planes=BLOCK_PLANES,
+                            fixed=2 * operand_bytes, min_rows=min_rows)
+    if block_m:
+        bm = chunk = min(block_m, m)
+    else:
+        bm = choose_block_m(m, row, planes=BLOCK_PLANES, min_rows=min_rows)
+        if limit(bm) > VMEM_CAP_BYTES:
+            bm = shared
+        chunk = min(bm, max(shared, MIN_CHUNK_ROWS))
+    return ShmBlock(bm, chunk, operand_bytes, shared, limit(bm))
 
 
 def _cdot(ar, ai, br, bi):
@@ -68,17 +119,16 @@ def _flip_row(y, rid, j):
                      pltpu.roll(y, n - s, 0))
 
 
-def make_shm_kernel(program: Sequence[Tuple], n_mats: int, n_tiles: int):
+def make_shm_kernel(program: Sequence[Tuple], n_mats: int, n_tiles: int,
+                    chunk: int):
     """Kernel body for a static member program. Entries:
     ``("tile", J, tidx)`` — out = sum_d tile[tidx[d]] * x(rows ^ d);
     ``("mat", J, midx)`` — out = sum_d sum_v [rowval == v] x(rows ^ d) @
-    mat[midx[d][v]]; ``J`` are the member's row bits (block-relative)."""
+    mat[midx[d][v]]; ``J`` are the member's row bits (block-relative).
+    ``chunk`` rows (a multiple of the window's period dividing the block)
+    go through the program at a time, in a loop unless that is the block."""
 
-    def body(sre_ref, sim_ref, *refs):
-        mats_ref = refs[0] if n_mats else None
-        tiles_ref = refs[1 if n_mats else 0] if n_tiles else None
-        ore_ref, oim_ref = refs[-2], refs[-1]
-        xr, xi = sre_ref[...], sim_ref[...]
+    def run(xr, xi, mats_ref, tiles_ref):
         rid = jax.lax.broadcasted_iota(jnp.int32, xr.shape, 0)
         for kind, J, idx in program:
             h = len(J)
@@ -109,8 +159,25 @@ def make_shm_kernel(program: Sequence[Tuple], n_mats: int, n_tiles: int):
                         acc_r = yr if acc_r is None else acc_r + yr
                         acc_i = yi if acc_i is None else acc_i + yi
             xr, xi = acc_r, acc_i
-        ore_ref[...] = xr
-        oim_ref[...] = xi
+        return xr, xi
+
+    def body(sre_ref, sim_ref, *refs):
+        mats_ref = refs[0] if n_mats else None
+        tiles_ref = refs[1 if n_mats else 0] if n_tiles else None
+        ore_ref, oim_ref = refs[-2], refs[-1]
+        bm = sre_ref.shape[0]
+        if chunk == bm:
+            ore_ref[...], oim_ref[...] = run(sre_ref[...], sim_ref[...],
+                                             mats_ref, tiles_ref)
+            return
+
+        def step(k, carry):
+            rows = pl.ds(pl.multiple_of(k * chunk, chunk), chunk)
+            ore_ref[rows, :], oim_ref[rows, :] = run(
+                sre_ref[rows, :], sim_ref[rows, :], mats_ref, tiles_ref)
+            return carry
+
+        jax.lax.fori_loop(0, bm // chunk, step, 0)
 
     return body
 
@@ -178,6 +245,7 @@ def shm_apply(
     *,
     block_m: int = 0,
     interpret: bool = True,
+    record: Optional[Callable[[ShmBlock], None]] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Apply ``gates`` inside ONE ``pallas_call``.
 
@@ -190,8 +258,8 @@ def shm_apply(
     ``gates``: (bits, op) pairs; a 2-D ``op`` is a unitary on ``bits`` (bit
     j of its index binds to bits[j]), a 1-D ``op`` a diagonal indexed by the
     values of ``bits``. ``block_m`` is the block's row count, a multiple of
-    the window's row period; 0 picks the largest block whose buffers and
-    lowered gate operands fit the VMEM budget.
+    the window's row period; 0 sizes it by :func:`shm_block`. ``record``
+    receives the call's :class:`ShmBlock`.
     """
     shape = sre.shape
     if shape[1] > LANES:
@@ -206,18 +274,22 @@ def shm_apply(
     with jax.named_scope("operands"):
         program, mats, tiles = _lower_members(gates, c, p_rows)
     operands = [x for x in (mats, tiles) if x is not None]
-    fixed = 2 * sum(x.size * x.dtype.itemsize for x in operands)
-    bm = min(block_m or choose_block_m(m, 4 * C, planes=BLOCK_PLANES,
-                                       fixed=fixed, min_rows=max(8, period)), m)
-    assert m % bm == 0 and bm % period == 0, (m, bm, period)
+    sizing = shm_block(m, C, period,
+                       sum(x.size * x.dtype.itemsize for x in operands),
+                       block_m)
+    if record is not None:
+        record(sizing)
+    bm, chunk = sizing.block, sizing.chunk
+    assert m % bm == 0 and bm % chunk == 0 and chunk % period == 0, (
+        m, bm, chunk, period)
     body = make_shm_kernel(program, 0 if mats is None else len(mats),
-                           0 if tiles is None else len(tiles))
+                           0 if tiles is None else len(tiles), chunk)
     spec = pl.BlockSpec((bm, C), lambda i: (i, 0))
-    op_specs = [pl.BlockSpec(x.shape, lambda i, _n=x.ndim: (0,) * _n)
+    op_specs = [pl.BlockSpec(x.shape, lambda i, _n=x.ndim: (0,) * _n,
+                             pipeline_mode=pl.Buffered(OPERAND_BUFFERS))
                 for x in operands]
-    limit = vmem_limit(BLOCK_PLANES * bm * 4 * C + fixed)
     params = ({"compiler_params": pltpu.CompilerParams(
-        vmem_limit_bytes=limit)} if limit else {})
+        vmem_limit_bytes=sizing.vmem_limit)} if sizing.vmem_limit else {})
     ore, oim = pl.pallas_call(
         body,
         grid=(m // bm,),

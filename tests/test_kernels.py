@@ -117,3 +117,93 @@ def test_apply_shm_shard_matches_sequential():
                            list(bits))
     np.testing.assert_allclose(np.asarray(out).reshape(-1),
                                np.asarray(ref).reshape(-1), atol=1e-4)
+
+
+# --------------------------------------------------------- shm block sizing
+
+MAT_BYTES = 2 * 128 * 128 * 4  # one lowered lane matrix, planar f32
+
+
+@pytest.mark.parametrize("n_mats,period", [(90, 8), (175, 64)])
+def test_shm_block_lane_heavy_group_gets_a_tall_block(n_mats, period):
+    """Lane matrices that crowd the block out of the shared budget (as in
+    su2random at n = 28) get VMEM of their own: a tall block, chunks of at
+    least MIN_CHUNK_ROWS, and a VMEM request under the chip's cap."""
+    from repro.kernels.layout import VMEM_CAP_BYTES
+    from repro.kernels.shm import MIN_CHUNK_ROWS, shm_block
+    s = shm_block(1 << 21, 128, period, n_mats * MAT_BYTES)
+    assert s.shared_block <= 64
+    assert s.block >= 512 and s.block % s.chunk == 0
+    assert s.chunk >= MIN_CHUNK_ROWS and s.chunk % period == 0
+    assert s.vmem_limit >= s.operand_bytes
+    assert s.vmem_limit <= VMEM_CAP_BYTES
+
+
+@pytest.mark.parametrize("operand_mb,period", [(1.0, 8), (1.3, 32), (1.5, 8)])
+def test_shm_block_light_group_keeps_its_block(operand_mb, period):
+    """A group whose operands fit beside its block (qft at n = 28: 1.0-1.3
+    MB, up to 3 MB double buffered) keeps the block the shared budget
+    gives, run unchunked."""
+    from repro.kernels.shm import shm_block
+    s = shm_block(1 << 21, 128, period, int(operand_mb * 2**20))
+    assert s.block == s.shared_block == s.chunk == 1024
+
+
+@pytest.mark.parametrize("block_m", [8, 64, 256])
+def test_shm_block_explicit_block_is_honoured(block_m):
+    from repro.kernels.shm import shm_block
+    s = shm_block(1 << 21, 128, 8, 90 * MAT_BYTES, block_m=block_m)
+    assert s.block == s.chunk == block_m
+
+
+def test_shm_block_operands_past_the_cap_share_the_budget():
+    """Operands too large to sit beside the tall block fall back to the
+    block sized against operands and blocks together."""
+    from repro.kernels.layout import VMEM_CAP_BYTES
+    from repro.kernels.shm import shm_block
+    s = shm_block(1 << 21, 128, 8, VMEM_CAP_BYTES - (2 << 20))
+    assert s.block == s.chunk == s.shared_block == 8
+
+
+def _chunked_group(rng, r, n_mixed):
+    """Members on a window of 7 lanes and r row bits: lane/row pairs (the
+    operand load that chunks the block), lane-only runs, row-only gates and
+    diagonals."""
+    top = 7 + r - 1
+    gates = [((0,), G.H), ((1,), G.gate_matrix("ry", [0.3])),
+             ((7, top), G.CX), ((top,), G.gate_matrix("rx", [0.9])),
+             ((2, top), np.exp(1j * rng.uniform(0, 6, 4)).astype(np.complex64)),
+             ((7,), np.exp(1j * rng.uniform(0, 6, 2)).astype(np.complex64))]
+    for i in range(n_mixed):
+        gates.append(((i % 7, 7 + i % r), _rand_unitary(rng, 2)))
+        if i % 4 == 3:
+            gates.append(((i % 7,), _rand_unitary(rng, 1)))
+    return [(b, jnp.asarray(np.asarray(m), jnp.complex64)) for b, m in gates]
+
+
+@pytest.mark.parametrize("r", [3, 6])
+def test_shm_chunked_block_matches_unchunked_and_ref(r):
+    """A block run in several chunks gives exactly the state of the same
+    group run one chunk per block, and matches the reference."""
+    rng = np.random.default_rng(r)
+    a, M = 7 + r, 512
+    gates = _chunked_group(rng, r, 12)
+    sre = jnp.asarray(rng.normal(size=(M, 128)).astype(np.float32))
+    sim = jnp.asarray(rng.normal(size=(M, 128)).astype(np.float32))
+    seen = []
+    o_re, o_im = shm_apply(sre, sim, gates, a, interpret=True,
+                           record=seen.append)
+    (s,) = seen
+    assert s.block == M and s.chunk == 128, s  # four chunks in one block
+    u_re, u_im = shm_apply(sre, sim, gates, a, block_m=s.chunk,
+                           interpret=True, record=seen.append)
+    assert seen[-1].block == seen[-1].chunk == s.chunk
+    np.testing.assert_array_equal(np.asarray(o_re), np.asarray(u_re))
+    np.testing.assert_array_equal(np.asarray(o_im), np.asarray(u_im))
+    dense = [(b, jnp.diag(m) if m.ndim == 1 else m) for b, m in gates]
+    r_re, r_im = shm_apply_ref(sre.reshape(-1, 1 << a), sim.reshape(-1, 1 << a),
+                               dense, a)
+    np.testing.assert_allclose(np.asarray(o_re).reshape(r_re.shape),
+                               np.asarray(r_re), atol=1e-4)
+    np.testing.assert_allclose(np.asarray(o_im).reshape(r_im.shape),
+                               np.asarray(r_im), atol=1e-4)

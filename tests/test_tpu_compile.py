@@ -92,6 +92,34 @@ def test_shm_kernel_compiles(one_chip, a):
     assert "%shm_group" in text
 
 
+def test_shm_kernel_compiles_lane_heavy_group(one_chip):
+    """An shm group whose lane matrices overflow the shared VMEM budget (92
+    matrices from 23 lane/row pairs at r = 3, as in su2random(28)) compiles
+    at a tall block run in chunks, with its VMEM request."""
+    from repro.kernels.shm import MIN_CHUNK_ROWS
+
+    rows, a = 1 << 21, 10
+    rng = np.random.default_rng(0)
+    gates = [((i % 7, 7 + i % 3), jnp.asarray(
+        np.linalg.qr(rng.normal(size=(4, 4)) + 1j * rng.normal(size=(4, 4)))[0],
+        jnp.complex64)) for i in range(23)]
+    gates += [((7, 9), jnp.asarray(G.CX, jnp.complex64)),
+              ((8,), jnp.asarray(np.exp(1j * np.arange(2)), jnp.complex64))]
+    seen = []
+
+    def f(sre, sim):
+        return shm_apply(sre, sim, gates, a, interpret=False,
+                         record=seen.append)
+
+    state = _sds((rows, 128), jnp.float32, one_chip)
+    text = jax.jit(f).lower(state, state).compile().as_text()
+    (s,) = seen
+    assert s.shared_block == 8 and s.block == 1024, s
+    assert s.chunk == MIN_CHUNK_ROWS, s
+    assert "%shm_group" in text
+    assert f'"size":"{s.vmem_limit}"' in text  # the kernel's scoped VMEM
+
+
 def test_pjit_qft28_fits_hbm(one_chip, monkeypatch):
     """The whole pjit stage program of qft(28) (2 GiB state) with the Pallas
     kernels compiles for one chip and fits its memory."""
