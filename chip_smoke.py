@@ -17,10 +17,10 @@ One chip, at the paper's single-chip size (n = 28, a 2 GiB complex64 state):
 (d) ``SimulationService`` in process answering 8 ``su2param(20)`` requests
     with distinct seeded bindings, each against ``simulate_np``.
 
-Four chips (``--chips 4``): ``ShardMapBackend`` and ``PjitBackend`` at
-n = 30, R = 2, L = 28 (2 GiB per chip) on the ``qft`` closed form, computed
-where each shard lives, and ``su2random(24)`` with R = 2 against
-``simulate_np``.
+Four chips (``--chips 4``): ``ShardMapBackend`` with the Pallas kernels
+and ``PjitBackend`` at n = 30, R = 2, L = 28 (2 GiB per chip) on the ``qft``
+closed form, computed where each shard lives, and ``su2random(24)`` with
+R = 2 against ``simulate_np``.
 
 Every engine is built with ``degrade=False`` and run with ``verify=False``:
 a build failure is a failure, never a silent fallback. Every phase prints
@@ -299,17 +299,21 @@ def _four_chip_backends() -> dict:
 
 
 def phase_four_qft() -> dict:
-    """qft(30), R = 2, on both backends: the state stays spread over the
-    four chips and the closed form is checked where each shard lives."""
+    """qft(30), R = 2, on both backends, shard_map with the Pallas kernels:
+    the state stays spread over the four chips and the closed form is
+    checked where each shard lives."""
     import numpy as np
     from repro.core import generators as gen
 
     n, R = SIZES["four"], 2
     x = int(np.random.default_rng(SEED + 2).integers(1 << n))
     kws = _four_chip_backends()
+    kws["shardmap"] = dict(kws["shardmap"], use_pallas=True)
     engs = {b: build(gen.qft(n), n - R, R, backend=b, **kw)
             for b, kw in kws.items()}
     info = dict(zip(engs, compile_all(list(engs.values()))))
+    if not info["shardmap"]["tpu_custom_call"]:
+        raise RuntimeError("no tpu_custom_call in the shard_map program")
     for backend, eng in engs.items():
         shape = eng.backend.shape if backend == "pjit" else (1 << n,)
         psi0 = basis_state(n, x, shape, eng.backend.sharding)

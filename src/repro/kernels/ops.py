@@ -8,7 +8,7 @@ a TPU, refused anywhere else. Nothing is decided at import time.
 
 from __future__ import annotations
 
-from typing import List, Sequence, Tuple
+from typing import Dict, Hashable, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -31,7 +31,7 @@ SHM_BLOCKS: List[ShmBlock] = []
 def reset_kernel_counters() -> None:
     for k in KERNEL_CALLS:
         KERNEL_CALLS[k] = 0
-    SHM_BLOCKS.clear()
+    _clear_records()
 
 
 def kernel_call_counts() -> dict:
@@ -123,3 +123,36 @@ def shm_kernel(x: jnp.ndarray,
     ore, oim = shm_apply(sre, sim, gates, window_bits, interpret=interp,
                          record=SHM_BLOCKS.append)
     return _from_planar(ore, oim, x.dtype)
+
+
+# Trace-time record of the remaps of each traced shard_map program, by
+# program: a second trace of one program (its ``lower()`` beside its run)
+# replaces the first record and adds nothing. (It and its reset sit below
+# the wrappers above: a Pallas call carries the source lines of the frames
+# that traced it, so lines added above them change every program's text.)
+REMAPS: Dict[Hashable, List[dict]] = {}
+
+
+def _clear_records() -> None:
+    SHM_BLOCKS.clear()
+    REMAPS.clear()
+
+
+def remap_record(slot, m: int, L: int, dtype, ppermute: bool) -> dict:
+    """One traced remap of a shard of ``2^L`` amplitudes of ``dtype``: its
+    ``slot`` (``"init"``, a stage index or ``"final"``), the ``m`` bits its
+    all-to-all exchanges, the bytes each device sends in it
+    (``(1 - 2^-m) * itemsize * 2^L``: all of its ``2^m`` chunks but its
+    own) and whether a ``ppermute`` follows."""
+    dt = jnp.dtype(dtype)
+    return {"slot": slot, "m": m, "L": L, "dtype": dt.name,
+            "itemsize": dt.itemsize,
+            "a2a_bytes": dt.itemsize * ((1 << L) - (1 << (L - m))),
+            "ppermute": ppermute}
+
+
+def remap_stats() -> dict:
+    """How many shard_map ``programs`` were traced, and their ``remaps``
+    (:func:`remap_record`, in stage order)."""
+    return {"programs": len(REMAPS),
+            "remaps": [r for rs in REMAPS.values() for r in rs]}

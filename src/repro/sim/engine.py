@@ -100,12 +100,12 @@ def _dep_index(op: Op, G: int, R: int, L: int) -> Optional[jnp.ndarray]:
     return idx
 
 
-#: The ``jax.named_scope`` of each stage op kind on the pjit path: the device
-#: trace carries it in each op's metadata (``op_name``), so device time can be
-#: read per kind. Inside them, :mod:`repro.sim.lanes` opens ``route``,
-#: :mod:`repro.kernels.ops` ``planar``, and the building of the kernels'
-#: operands from the op tensors ``operands``; remaps and the logical-order
-#: reshape run under ``remap``.
+#: The ``jax.named_scope`` of each stage op kind (pjit and shard_map): the
+#: device trace carries it in each op's metadata (``op_name``), so device
+#: time can be read per kind. Inside them, :mod:`repro.sim.lanes` opens
+#: ``route``, :mod:`repro.kernels.ops` ``planar``, the making of the kernels'
+#: operands ``operands``; remaps and the logical-order reshape run under
+#: ``remap``, and the shard_map path's collectives under ``a2a``.
 OP_SCOPES = {"shm": "shm", "fused": "fused", "diag": "diag", "scalar": "diag"}
 
 
@@ -270,11 +270,11 @@ def _apply_remap_plan(shard, rp: RemapPlan, L: int, axis_names) -> jnp.ndarray:
         # 2^m chunks of the top bits, lanes kept whole when they fit
         l = lanes.lane_bits(L)
         x = x.reshape((1 << m, -1, 1 << l) if L - m >= l else (1 << m, -1))
-        x = lax.all_to_all(x, rp.a2a_axes, split_axis=0, concat_axis=0, tiled=True)
-        # tiled=True keeps dim0 = 2^m (split into 2^m chunks, exchanged,
-        # re-concatenated along the same axis)
+        with jax.named_scope("a2a"):  # tiled: dim 0 keeps its 2^m chunks
+            x = lax.all_to_all(x, rp.a2a_axes, split_axis=0, concat_axis=0, tiled=True)
     if rp.ppermute is not None:
-        x = lax.ppermute(x, axis_names, perm=list(rp.ppermute))
+        with jax.named_scope("a2a"):
+            x = lax.ppermute(x, axis_names, perm=list(rp.ppermute))
     return lanes.permute(x.reshape(shard.shape), _axes_to_bits(rp.post_perm, L),
                          [L - 1 - ax for ax in rp.post_flip_axes])
 
@@ -728,6 +728,7 @@ class ShardMapBackend(Backend):
     def _device_fn(self, shard, consts, apply_final: bool = True):
         self.engine.xla_compiles += 1  # trace-time side effect
         l = lanes.lane_bits(self.engine.L)
+        self._remaps = _remap_record_of(self, apply_final)
         out = self.engine.stage_loop(
             shard.reshape(-1, 1 << l),  # lane-dense (rows, lanes)
             lambda v, prog: self._apply_ops(v, prog, consts),
@@ -736,18 +737,19 @@ class ShardMapBackend(Backend):
         return out.reshape(-1)
 
     def _remap(self, view, slot, spec: RemapSpec):
-        return _apply_remap_plan(view, self._plans[slot], self.engine.L, self.axis_names)
+        _record_remap(self, slot)
+        with jax.named_scope("remap"):
+            return _apply_remap_plan(view, self._plans[slot], self.engine.L, self.axis_names)
 
     def _apply_ops(self, view, prog: StageProgram, consts):
         for op in prog.ops:
-            view = self._apply_op(view, op, consts)
+            with jax.named_scope(OP_SCOPES[op.kind]):
+                view = self._apply_op(view, op, consts)
         return view
 
     def _dep_idx(self, op: Op):
-        idx = 0
-        for j, p in enumerate(op.dep_bits):
-            idx = idx + (lax.axis_index(f"b{p}").astype(jnp.int32) << j)
-        return idx
+        return sum(lax.axis_index(f"b{p}").astype(jnp.int32) << j
+                   for j, p in enumerate(op.dep_bits))
 
     def _select(self, op: Op, consts):
         """Per-device tensor slice: dep-batched variant via ``lax.axis_index``."""
@@ -766,8 +768,7 @@ class ShardMapBackend(Backend):
         if op.kind == "scalar":
             return view * Tsel
         if op.kind == "diag":
-            return lanes.apply_diag(view, Tsel, op.local_bits,
-                                    lanes.lane_bits(eng.L))
+            return lanes.apply_diag(view, Tsel, op.local_bits, lanes.lane_bits(eng.L))
         return _apply_fused(view, Tsel, op.local_bits, eng.L, eng.use_pallas)
 
     def _apply_shm(self, view, op: Op, consts):
@@ -779,18 +780,17 @@ class ShardMapBackend(Backend):
             for m in op.gates:
                 view = self._apply_op(view, m, consts)
             return view
-        gate_list, scal = _shm_operands(op, lambda m: self._select(m, consts))
+        with jax.named_scope("operands"):
+            gate_list, scal = _shm_operands(op, lambda m: self._select(m, consts))
         if not gate_list:
             return view * scal
         return lanes.apply_shm_group(view, gate_list, op.local_bits)
 
     # ---------------------------------------------------------------- api
     def _fn(self, apply_final: bool):
-        fn = self._fns.get(apply_final)
-        if fn is None:
-            fn = self._make_fn(apply_final)
-            self._fns[apply_final] = fn
-        return fn
+        if apply_final not in self._fns:
+            self._fns[apply_final] = self._make_fn(apply_final)
+        return self._fns[apply_final]
 
     def prepare(self, psi0, batch: bool = False):
         eng = self.engine
@@ -2248,3 +2248,29 @@ def engine_for(
             optimize=optimize, cache=None, backend_kw=backend_kw,
             storage=storage, degrade=degrade, **plan_kw)
     return eng
+
+
+# ======================================================================
+# Trace-time record of the shard_map path's remaps (at the module's end: a
+# Pallas call carries the source lines of the frames that traced it, so
+# lines added above the pjit path or the stage loop change their programs)
+# ======================================================================
+
+
+def _remap_record_of(backend: ShardMapBackend, apply_final: bool) -> List[dict]:
+    """A fresh record for one trace of ``backend``'s program, registered in
+    :data:`repro.kernels.ops.REMAPS` under the program: a second trace of
+    it replaces the first."""
+    from ..kernels import ops as kops
+
+    remaps = kops.REMAPS[(id(backend), apply_final)] = []
+    return remaps
+
+
+def _record_remap(backend: ShardMapBackend, slot) -> None:
+    """Record the remap at ``slot`` of the program being traced."""
+    from ..kernels import ops as kops
+
+    eng, rp = backend.engine, backend._plans[slot]
+    backend._remaps.append(kops.remap_record(slot, rp.m, eng.L, eng.dtype,
+                                             rp.ppermute is not None))
