@@ -39,12 +39,15 @@ def kernel_call_counts() -> dict:
 
 
 def shm_block_stats() -> dict:
-    """The traced shm calls' block rows, chunk rows and operand bytes, and
-    how many got another block than sizing operands and blocks against one
-    budget gives (``resized``)."""
+    """The traced shm calls' block rows, chunk rows and operand bytes; how
+    many got another block than sizing operands and blocks against one
+    budget gives (``resized``); and the calls' lane ``products`` (in
+    whole-chunk dots) and ``mixed`` lane/row members, summed."""
     return {
         "calls": len(SHM_BLOCKS),
         "resized": sum(b.block != b.shared_block for b in SHM_BLOCKS),
+        "products": sum(b.products for b in SHM_BLOCKS),
+        "mixed": sum(b.mixed for b in SHM_BLOCKS),
         "blocks": [b.block for b in SHM_BLOCKS],
         "chunks": [b.chunk for b in SHM_BLOCKS],
         "operand_bytes": [b.operand_bytes for b in SHM_BLOCKS],

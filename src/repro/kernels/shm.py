@@ -5,17 +5,22 @@ hierarchy).
 The state arrives lane-dense as planar fp32 ``[M, C]`` (``C = 2^c`` lanes,
 ``c <= 7``; 128 at every real size). The group's *window* covers the ``c``
 lane bits and the lowest ``r`` row bits, so a ``(BR, C)`` block with ``BR``
-a multiple of ``2^r`` holds whole windows. The block is loaded into VMEM
-once and every member gate runs on it — one HBM read+write pass in total,
-whatever the gate count (the ``alpha + sum_g cost(g)`` regime of the cost
-model):
+a multiple of ``P = 2^r`` holds whole windows. The block is loaded into
+VMEM once and every member gate runs on it — one HBM read+write pass in
+total, whatever the gate count (the ``alpha + sum_g cost(g)`` regime of the
+cost model). The members run on the rows grouped by window row value: slab
+``u`` holds the rows whose ``r`` window row bits read ``u`` (one strided
+load and store each), and the slabs lie side by side in ``u`` order:
 
 * a gate that touches lane bits is a complex MXU matmul against its lane
   blocks (the gate embedded in the ``c``-bit lane space, one block per pair
-  of row-bit values); runs of lane-only gates are pre-multiplied into one
-  matrix;
-* a gate on row bits only is row-pair arithmetic: the partner rows come from
-  sublane rolls, the coefficients from periodic ``(P, C)`` tiles;
+  of row-bit values): for each value of its ``h`` row bits, the slabs that
+  hold it go through the MXU side by side, so it runs ``4^h`` dots on
+  ``1/2^h`` of the rows each; runs of lane-only gates are pre-multiplied
+  into one matrix;
+* a gate on row bits only is slab arithmetic: the partner rows are the
+  slabs whose index differs in those bits, the coefficients rows of
+  periodic ``(P, C)`` tiles, each broadcast over its slab;
 * a diagonal member is one elementwise multiply by its periodic tile; runs
   of diagonals are pre-multiplied into one tile.
 
@@ -34,11 +39,12 @@ from typing import Callable, List, NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .layout import (PRECISION, VMEM_CAP_BYTES, ascending, choose_block_m,
-                     embed, gather_bits, vmem_limit)
+                     embed, gather_bits, scatter_bits, vmem_limit)
 
 LANES = 128
 
@@ -63,6 +69,8 @@ class ShmBlock(NamedTuple):
     operand_bytes: int  # lowered lane matrices and tiles
     shared_block: int   # the block when operands and blocks share the budget
     vmem_limit: int     # ``vmem_limit_bytes`` asked for (0: the default)
+    products: int = 0   # lane products a call runs, in whole-chunk dots
+    mixed: int = 0      # lane matrix members on window row bits too
 
 
 def shm_block(m: int, C: int, period: int, operand_bytes: int,
@@ -77,8 +85,10 @@ def shm_block(m: int, C: int, period: int, operand_bytes: int,
     the rows it runs on times its member program, and the operands' bytes
     grow with the program, so the program runs on chunks of the block that
     operands and blocks would share the budget for, never fewer than
-    ``MIN_CHUNK_ROWS``; where that is the whole block, unchunked. An
-    explicit ``block_m`` is the block, run unchunked."""
+    ``MIN_CHUNK_ROWS`` nor than eight rows a window row value (whole
+    ``(8, 128)`` tiles in each of the body's ``period`` slabs); where that
+    is the whole block, unchunked. An explicit ``block_m`` is the block,
+    run unchunked."""
     row = 4 * C
     min_rows = max(8, period)
     limit = lambda bm: vmem_limit(  # noqa: E731
@@ -91,74 +101,99 @@ def shm_block(m: int, C: int, period: int, operand_bytes: int,
         bm = choose_block_m(m, row, planes=BLOCK_PLANES, min_rows=min_rows)
         if limit(bm) > VMEM_CAP_BYTES:
             bm = shared
-        chunk = min(bm, max(shared, MIN_CHUNK_ROWS))
+        chunk = min(bm, max(shared, MIN_CHUNK_ROWS, 8 * period))
     return ShmBlock(bm, chunk, operand_bytes, shared, limit(bm))
 
 
 def _cdot(ar, ai, br, bi):
-    d = lambda u, v: jnp.dot(u, v, precision=PRECISION,  # noqa: E731
-                             preferred_element_type=jnp.float32)
-    return d(ar, br) - d(ai, bi), d(ar, bi) + d(ai, br)
+    d = lambda u, v: lax.dot_general(  # noqa: E731
+        u, v, (((1,), (0,)), ((), ())), precision=PRECISION,
+        preferred_element_type=jnp.float32)
+    return (lax.sub(d(ar, br), d(ai, bi)), lax.add(d(ar, bi), d(ai, br)))
 
 
-def _tile_mul(xr, xi, tr, ti):
-    """Complex multiply of a (BR, C) block by a periodic (P, C) tile."""
-    br, c = xr.shape
-    p = tr.shape[0]
-    x3r, x3i = xr.reshape(br // p, p, c), xi.reshape(br // p, p, c)
-    yr = x3r * tr[None] - x3i * ti[None]
-    yi = x3r * ti[None] + x3i * tr[None]
-    return yr.reshape(br, c), yi.reshape(br, c)
+# The body is traced slab by slab, so it binds ``lax`` primitives: a
+# ``jnp`` operator would add a nested trace per slab and per member.
 
 
-def _flip_row(y, rid, j):
-    """Rows with bit ``j`` toggled (sublane/row roll + select)."""
-    n = y.shape[0]
-    s = 1 << j
-    return jnp.where(((rid >> j) & 1) == 1, pltpu.roll(y, s, 0),
-                     pltpu.roll(y, n - s, 0))
+def _rows(parts):
+    """Slabs side by side along rows."""
+    return parts[0] if len(parts) == 1 else lax.concatenate(parts, 0)
+
+
+def _split(x, k: int):
+    """``x`` cut along rows into ``k`` equal slabs."""
+    return [x] if k == 1 else lax.split(x, (x.shape[0] // k,) * k, 0)
+
+
+def _add(acc, y):
+    return y if acc is None else lax.add(acc, y)
+
+
+def _per_slab(t, P: int, S: int):
+    """Rows ``0..P-1`` of a periodic ``(p, C)`` tile, row ``u`` broadcast
+    over slab ``u``'s ``S`` rows, slabs side by side."""
+    C = t.shape[1]
+    t = lax.slice(t, (0, 0), (P, C))
+    return lax.reshape(lax.broadcast_in_dim(t, (P, S, C), (0, 2)), (P * S, C))
 
 
 def make_shm_kernel(program: Sequence[Tuple], n_mats: int, n_tiles: int,
-                    chunk: int):
-    """Kernel body for a static member program. Entries:
-    ``("tile", J, tidx)`` — out = sum_d tile[tidx[d]] * x(rows ^ d);
-    ``("mat", J, midx)`` — out = sum_d sum_v [rowval == v] x(rows ^ d) @
-    mat[midx[d][v]]; ``J`` are the member's row bits (block-relative).
-    ``chunk`` rows (a multiple of the window's period dividing the block)
-    go through the program at a time, in a loop unless that is the block."""
+                    chunk: int, period: int):
+    """Kernel body for a static member program. The member program runs on
+    ``chunk`` rows (a multiple of the window's period ``P`` dividing the
+    block) at a time, in a loop unless that is the block, grouped by
+    window row value: slab ``u`` holds the chunk's rows whose window row
+    value is ``u`` (rows ``u, u + P, ...``; one strided load and store),
+    and the slabs lie side by side in ``u`` order. Entries, with ``J`` the
+    member's row bits (block-relative) and ``D(d)`` the slab offset of
+    ``d``'s bits on them:
+    ``("tile", J, tidx)`` — slab u = sum_d tile[tidx[d]][u] * slab(u ^ D(d));
+    ``("mat", J, midx)`` — for each value ``v`` of the bits ``J``, the slabs
+    holding it, side by side: sum_d slabs(^ D(d)) @ mat[midx[d][v]]."""
+    P = period
 
     def run(xr, xi, mats_ref, tiles_ref):
-        rid = jax.lax.broadcasted_iota(jnp.int32, xr.shape, 0)
+        S = xr.shape[0] // P
         for kind, J, idx in program:
             h = len(J)
-            parts = [(xr, xi)]
-            for d in range(1, 1 << h):
-                j = (d & -d).bit_length() - 1  # lowest set bit of d
-                pr, pi = parts[d & (d - 1)]
-                parts.append((_flip_row(pr, rid, J[j]), _flip_row(pi, rid, J[j])))
-            acc_r = acc_i = None
+            flips = scatter_bits(np.arange(1 << h), J).tolist()
+            parts = (_split(xr, P), _split(xi, P)) if h else None
+
+            def pick(us, f):
+                """Slabs ``u ^ f`` for ``u`` in ``us``, side by side."""
+                if not f and len(us) == P:
+                    return xr, xi
+                return tuple(_rows([p[u ^ f] for u in us]) for p in parts)
+
             if kind == "tile":
+                acc_r = acc_i = None
+                for d, i in enumerate(idx):
+                    ar, ai = pick(range(P), flips[d])
+                    tr, ti = (_per_slab(tiles_ref[i, k], P, S) for k in (0, 1))
+                    acc_r = _add(acc_r, lax.sub(lax.mul(ar, tr),
+                                                lax.mul(ai, ti)))
+                    acc_i = _add(acc_i, lax.add(lax.mul(ar, ti),
+                                                lax.mul(ai, tr)))
+                xr, xi = acc_r, acc_i
+                continue
+            if not h:  # lane-only: one product over all slabs
+                m = mats_ref[idx[0][0]]
+                xr, xi = _cdot(xr, xi, m[0], m[1])
+                continue
+            vals = gather_bits(np.arange(P), J)
+            out_r, out_i = [None] * P, [None] * P
+            for v in range(1 << h):
+                us = np.flatnonzero(vals == v).tolist()
+                acc_r = acc_i = None
                 for d in range(1 << h):
-                    t = tiles_ref[idx[d]]
-                    yr, yi = _tile_mul(*parts[d], t[0], t[1])
-                    acc_r = yr if acc_r is None else acc_r + yr
-                    acc_i = yi if acc_i is None else acc_i + yi
-            else:
-                rowval = 0
-                for i, jb in enumerate(J):
-                    rowval = rowval + (((rid >> jb) & 1) << i)
-                for d in range(1 << h):
-                    for v in range(1 << h):
-                        m = mats_ref[idx[d][v]]
-                        yr, yi = _cdot(*parts[d], m[0], m[1])
-                        if h:
-                            sel = rowval == v
-                            yr = jnp.where(sel, yr, 0.0)
-                            yi = jnp.where(sel, yi, 0.0)
-                        acc_r = yr if acc_r is None else acc_r + yr
-                        acc_i = yi if acc_i is None else acc_i + yi
-            xr, xi = acc_r, acc_i
+                    m = mats_ref[idx[d][v]]
+                    yr, yi = _cdot(*pick(us, flips[d]), m[0], m[1])
+                    acc_r, acc_i = _add(acc_r, yr), _add(acc_i, yi)
+                for u, yr, yi in zip(us, _split(acc_r, len(us)),
+                                     _split(acc_i, len(us))):
+                    out_r[u], out_i[u] = yr, yi
+            xr, xi = _rows(out_r), _rows(out_i)
         return xr, xi
 
     def body(sre_ref, sim_ref, *refs):
@@ -166,18 +201,24 @@ def make_shm_kernel(program: Sequence[Tuple], n_mats: int, n_tiles: int,
         tiles_ref = refs[1 if n_mats else 0] if n_tiles else None
         ore_ref, oim_ref = refs[-2], refs[-1]
         bm = sre_ref.shape[0]
+
+        def step(base):
+            rows = [pl.ds(base + u, chunk // P, stride=P) for u in range(P)]
+            yr, yi = run(_rows([sre_ref[r, :] for r in rows]),
+                         _rows([sim_ref[r, :] for r in rows]),
+                         mats_ref, tiles_ref)
+            for r, sr, si in zip(rows, _split(yr, P), _split(yi, P)):
+                ore_ref[r, :], oim_ref[r, :] = sr, si
+
         if chunk == bm:
-            ore_ref[...], oim_ref[...] = run(sre_ref[...], sim_ref[...],
-                                             mats_ref, tiles_ref)
+            step(0)
             return
 
-        def step(k, carry):
-            rows = pl.ds(pl.multiple_of(k * chunk, chunk), chunk)
-            ore_ref[rows, :], oim_ref[rows, :] = run(
-                sre_ref[rows, :], sim_ref[rows, :], mats_ref, tiles_ref)
+        def loop(k, carry):
+            step(pl.multiple_of(k * chunk, chunk))
             return carry
 
-        jax.lax.fori_loop(0, bm // chunk, step, 0)
+        jax.lax.fori_loop(0, bm // chunk, loop, 0)
 
     return body
 
@@ -276,14 +317,16 @@ def shm_apply(
     operands = [x for x in (mats, tiles) if x is not None]
     sizing = shm_block(m, C, period,
                        sum(x.size * x.dtype.itemsize for x in operands),
-                       block_m)
+                       block_m)._replace(
+        products=sum(1 << len(J) for kind, J, _ in program if kind == "mat"),
+        mixed=sum(kind == "mat" and bool(J) for kind, J, _ in program))
     if record is not None:
         record(sizing)
     bm, chunk = sizing.block, sizing.chunk
     assert m % bm == 0 and bm % chunk == 0 and chunk % period == 0, (
         m, bm, chunk, period)
     body = make_shm_kernel(program, 0 if mats is None else len(mats),
-                           0 if tiles is None else len(tiles), chunk)
+                           0 if tiles is None else len(tiles), chunk, period)
     spec = pl.BlockSpec((bm, C), lambda i: (i, 0))
     op_specs = [pl.BlockSpec(x.shape, lambda i, _n=x.ndim: (0,) * _n,
                              pipeline_mode=pl.Buffered(OPERAND_BUFFERS))

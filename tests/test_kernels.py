@@ -184,9 +184,12 @@ def _chunked_group(rng, r, n_mixed):
 @pytest.mark.parametrize("r", [3, 6])
 def test_shm_chunked_block_matches_unchunked_and_ref(r):
     """A block run in several chunks gives exactly the state of the same
-    group run one chunk per block, and matches the reference."""
+    group run one chunk per block, and matches the reference. A chunk holds
+    at least eight rows of each of the window's 2^r row values."""
+    from repro.kernels.shm import MIN_CHUNK_ROWS
     rng = np.random.default_rng(r)
-    a, M = 7 + r, 512
+    chunk = max(MIN_CHUNK_ROWS, 8 << r)
+    a, M = 7 + r, 4 * chunk
     gates = _chunked_group(rng, r, 12)
     sre = jnp.asarray(rng.normal(size=(M, 128)).astype(np.float32))
     sim = jnp.asarray(rng.normal(size=(M, 128)).astype(np.float32))
@@ -194,7 +197,9 @@ def test_shm_chunked_block_matches_unchunked_and_ref(r):
     o_re, o_im = shm_apply(sre, sim, gates, a, interpret=True,
                            record=seen.append)
     (s,) = seen
-    assert s.block == M and s.chunk == 128, s  # four chunks in one block
+    # several chunks in a block: four at r = 3, two in each of two blocks
+    # at r = 6 (the block's VMEM budget stops at 1024 rows)
+    assert s.block == min(M, 1024) and s.chunk == chunk, s
     u_re, u_im = shm_apply(sre, sim, gates, a, block_m=s.chunk,
                            interpret=True, record=seen.append)
     assert seen[-1].block == seen[-1].chunk == s.chunk
@@ -207,3 +212,73 @@ def test_shm_chunked_block_matches_unchunked_and_ref(r):
                                np.asarray(r_re), atol=1e-4)
     np.testing.assert_allclose(np.asarray(o_im).reshape(r_im.shape),
                                np.asarray(r_im), atol=1e-4)
+
+
+# ------------------------------------------- shm members by window row value
+
+
+def _row_value_group(rng, r, J, h, n_mixed):
+    """Members on a window of 7 lanes and r row bits around ``n_mixed``
+    lane/row members with h row bits (row bit ``J`` first): lane-only runs
+    between them, row-only gates and diagonals."""
+    rows = [J, (J + 1) % r][:h]
+    gates = [((0,), G.H), ((1,), G.gate_matrix("ry", [0.3])),
+             ((3, 7 + J), np.exp(1j * rng.uniform(0, 6, 4)).astype(np.complex64))]
+    if r > 1:
+        gates.append(((7 + J, 7 + (J + 1) % r), G.CX))
+    for i in range(n_mixed):
+        gates.append(((i % 7,) + tuple(7 + b for b in rows),
+                      _rand_unitary(rng, 1 + h)))
+        gates.append(((7 + (J + i) % r,), G.gate_matrix("rx", [0.4 + i])))
+        gates.append((((i + 2) % 7,), _rand_unitary(rng, 1)))
+    gates.append(((7 + r - 1,), np.exp(1j * rng.uniform(0, 6, 2)).astype(np.complex64)))
+    return [(b, jnp.asarray(np.asarray(m), jnp.complex64)) for b, m in gates]
+
+
+@pytest.mark.parametrize("r,J,h", [(r, J, h) for r in (1, 3, 6)
+                                   for J in sorted({0, r - 1})
+                                   for h in (1, 2) if h <= r])
+def test_shm_row_value_members_match_ref(r, J, h):
+    """Lane/row members on the lowest and the highest window row bit, with
+    one and two row bits, run on the rows of each row value: chunked and
+    unchunked blocks give the same state, and it matches the reference."""
+    rng = np.random.default_rng(10 * r + J + h)
+    a, M = 7 + r, 1024
+    gates = _row_value_group(rng, r, J, h, 6 if h == 1 else 2)
+    sre = jnp.asarray(rng.normal(size=(M, 128)).astype(np.float32))
+    sim = jnp.asarray(rng.normal(size=(M, 128)).astype(np.float32))
+    seen = []
+    o_re, o_im = shm_apply(sre, sim, gates, a, interpret=True,
+                           record=seen.append)
+    assert seen[0].chunk < seen[0].block, seen[0]  # chunked
+    u_re, u_im = shm_apply(sre, sim, gates, a, block_m=seen[0].chunk,
+                           interpret=True, record=seen.append)
+    assert seen[1].chunk == seen[1].block  # unchunked
+    np.testing.assert_array_equal(np.asarray(o_re), np.asarray(u_re))
+    np.testing.assert_array_equal(np.asarray(o_im), np.asarray(u_im))
+    dense = [(b, jnp.diag(m) if m.ndim == 1 else m) for b, m in gates]
+    r_re, r_im = shm_apply_ref(sre.reshape(-1, 1 << a), sim.reshape(-1, 1 << a),
+                               dense, a)
+    np.testing.assert_allclose(np.asarray(o_re).reshape(r_re.shape),
+                               np.asarray(r_re), atol=1e-4)
+    np.testing.assert_allclose(np.asarray(o_im).reshape(r_im.shape),
+                               np.asarray(r_im), atol=1e-4)
+
+
+def test_shm_block_stats_count_products_and_mixed_members():
+    """Four lane/row members with one row bit (two whole-chunk products
+    each) and one lane-only run (one) make 9 products and 4 mixed members;
+    row-only gates and diagonals run none."""
+    from repro.kernels import ops as kops
+
+    rng = np.random.default_rng(3)
+    gates = [((0,), G.H), ((1,), G.T), ((8,), G.H), ((7, 8), G.CX),
+             ((2, 7), np.exp(1j * rng.uniform(0, 6, 4)).astype(np.complex64))]
+    gates += [((i, 7 + i % 2), _rand_unitary(rng, 2)) for i in range(4)]
+    x = jnp.asarray((rng.normal(size=(64, 128)) + 1j * rng.normal(size=(64, 128)))
+                    .astype(np.complex64))
+    kops.reset_kernel_counters()
+    kops.shm_kernel(x, [(b, jnp.asarray(np.asarray(m), jnp.complex64))
+                        for b, m in gates], 9)
+    stats = kops.shm_block_stats()
+    assert (stats["calls"], stats["products"], stats["mixed"]) == (1, 9, 4)

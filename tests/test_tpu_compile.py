@@ -120,6 +120,34 @@ def test_shm_kernel_compiles_lane_heavy_group(one_chip):
     assert f'"size":"{s.vmem_limit}"' in text  # the kernel's scoped VMEM
 
 
+def test_shm_kernel_compiles_mixed_group_by_row_value(one_chip):
+    """The shape of su2random(28)'s largest shm group: 42 lane/row members
+    over a window of 7 lanes and r = 6 row bits, with 7 lane-only members
+    between them. Run on the rows of each window row value, a lane/row
+    member costs two whole-chunk products, not four: 91 in all, not 175."""
+    rows, a = 1 << 21, 13
+    rng = np.random.default_rng(1)
+    gates = []
+    for i in range(42):
+        q = np.linalg.qr(rng.normal(size=(4, 4)) + 1j * rng.normal(size=(4, 4)))[0]
+        gates.append(((i % 7, 7 + i % 6), jnp.asarray(q, jnp.complex64)))
+        if i % 6 == 5:
+            gates.append((((i + 3) % 7,), jnp.asarray(G.H, jnp.complex64)))
+    seen = []
+
+    def f(sre, sim):
+        return shm_apply(sre, sim, gates, a, interpret=False,
+                         record=seen.append)
+
+    state = _sds((rows, 128), jnp.float32, one_chip)
+    text = jax.jit(f).lower(state, state).compile().as_text()
+    (s,) = seen
+    assert (s.products, s.mixed) == (91, 42), s
+    assert s.chunk == 512 and s.block == 1024, s  # eight rows a row value
+    assert "%shm_group" in text
+    assert f'"size":"{s.vmem_limit}"' in text  # the kernel's scoped VMEM
+
+
 def test_pjit_qft28_fits_hbm(one_chip, monkeypatch):
     """The whole pjit stage program of qft(28) (2 GiB state) with the Pallas
     kernels compiles for one chip and fits its memory."""
