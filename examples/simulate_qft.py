@@ -1,8 +1,8 @@
 """Distributed quantum circuit simulation with explicit collectives.
 
-Runs a 20-qubit QFT across 8 (virtual) devices with the production
-shard_map executor — the same engine the 512-chip dry-run lowers — and
-compares all three execution paths (pjit, shard_map, host-offloaded).
+Runs a 20-qubit QFT across 8 (virtual) devices through ``engine_for`` and
+compares the staged backends (pjit, shard_map, host-offloaded) against the
+dense reference.
 
     PYTHONPATH=src python examples/simulate_qft.py
 """
@@ -18,11 +18,8 @@ import time
 import numpy as np
 
 from repro.core.generators import qft
-from repro.core.partition import partition
-from repro.sim.executor import StagedExecutor
+from repro.sim.engine import engine_for
 from repro.sim.measure import expectation_np, marginal_np, simulate_and_measure
-from repro.sim.offload import OffloadedExecutor
-from repro.sim.shardmap_executor import ShardMapExecutor
 from repro.sim.statevector import fidelity, simulate
 
 
@@ -37,23 +34,22 @@ def timed(name, fn):
 def main():
     n, L, R, G = 20, 17, 2, 1
     circuit = qft(n)
-    plan = partition(circuit, L, R, G)
+    pjit = engine_for(circuit, L, R, G)
+    plan = pjit.plan
     print(f"qft({n}): {circuit.n_gates} gates -> {plan.n_stages} stages, "
           f"{sum(len(s.kernels) for s in plan.stages)} kernels "
           f"(2^{L} amps/shard on {1 << (R + G)} devices)")
 
+    engines = {
+        "pjit (GSPMD)": pjit,
+        "shard_map": engine_for(circuit, L, R, G, plan=plan,
+                                backend="shardmap"),
+        "shard_map+pallas": engine_for(circuit, L, R, G, plan=plan,
+                                       backend="shardmap", use_pallas=True),
+        "offloaded": engine_for(circuit, L, n - L, 0, backend="offload"),
+    }
     ref = np.asarray(simulate(circuit))
-    outs = {}
-    outs["pjit (GSPMD)"] = timed(
-        "pjit (GSPMD collectives)", lambda: StagedExecutor(circuit, plan).run())
-    outs["shard_map"] = timed(
-        "shard_map (explicit a2a)", lambda: ShardMapExecutor(circuit, plan).run())
-    outs["shard_map+pallas"] = timed(
-        "shard_map + Pallas kernels",
-        lambda: ShardMapExecutor(circuit, plan, use_pallas=True).run())
-    outs["offloaded"] = timed(
-        "host-DRAM offloaded", lambda: OffloadedExecutor(
-            circuit, partition(circuit, L, n - L, 0)).run())
+    outs = {name: timed(name, eng.run) for name, eng in engines.items()}
 
     for name, out in outs.items():
         f = fidelity(out, ref)

@@ -2,7 +2,7 @@
 
 At n = 28 a cold compile of one stage program takes seconds to minutes, so
 every entry point (``launch.simulate``, ``launch.serve_sim``,
-``benchmarks/run.py``, ``chip_smoke.py``) calls :func:`enable_compile_cache`
+``chip_smoke.py``) calls :func:`enable_compile_cache`
 before its first compile. The directory is ``$JAX_COMPILATION_CACHE_DIR``
 when that is set (no other cache is configured then), otherwise the fixed
 ``<checkout>/.jax_cache``. The path never depends on a temp name, a pid or

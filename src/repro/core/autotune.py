@@ -16,7 +16,7 @@ truth is wall time on the actual device. This module closes the loop:
 
 Winners are also registered in the in-process :data:`TUNED` table keyed by
 ``(CircuitKey digest, device-fingerprint digest)`` — the serve metrics
-snapshot and ``benchmarks/run.py --json`` surface these outcomes.
+snapshot surfaces these outcomes.
 
 Tuning is explicitly opt-in (it pays ~len(candidates) plan+compile+replay
 costs up front); nothing here runs on the default serving path.

@@ -246,9 +246,9 @@ def redundant(n: int, reps: int = 2, seed: int = 29) -> Circuit:
       so only commutation-aware reordering can sink them together.
 
     A qft-like entangling backbone survives optimization, keeping the
-    planned circuit non-trivial. Used by ``benchmarks/bench_optimize.py``,
-    where the optimizer must *strictly* reduce both gate count and stage
-    count on this family.
+    planned circuit non-trivial. ``tests/test_optimize.py`` checks that the
+    optimizer *strictly* reduces both gate count and stage count on this
+    family.
     """
     rng = np.random.default_rng(seed)
     c = Circuit(n)

@@ -12,14 +12,14 @@ backend gathers the 2^n probability vector to one device):
       --L 17 --R 3 --executor offload --shots 1024 \
       --marginal 0,1,2 --observable "Z0 Z1 + 0.5*X2"
 
-Unified engine (serving path: compile cache + batched initial states):
+Batched initial states (the engine's fused batch path):
   PYTHONPATH=src python -m repro.launch.simulate --circuit qft --n 18 \
-      --L 15 --R 3 --executor offload --engine --batch 4 --shots 256
+      --L 15 --R 3 --executor offload --batch 4 --shots 256
 
 Parameterized circuits (structure/parameter split — the compile cache is
 structural, so rebinding angles never re-runs ILP/DP/XLA):
   PYTHONPATH=src python -m repro.launch.simulate --circuit isingparam --n 12 \
-      --L 10 --R 2 --engine --bind J=0.35 --bind h=0.8 --check
+      --L 10 --R 2 --bind J=0.35 --bind h=0.8 --check
   PYTHONPATH=src python -m repro.launch.simulate --circuit su2param --n 10 \
       --L 10 --sweep points.json --check
 (points.json: a JSON list of {name: value} objects, or {"name": [v0, v1, ...]}
@@ -38,7 +38,7 @@ from jax.sharding import AxisType
 
 from ..compile_cache import enable_compile_cache
 from ..core.generators import FAMILIES, PARAM_FAMILIES
-from ..core.partition import partition
+from ..sim.engine import DEFAULT_CACHE, engine_for
 from ..sim.statevector import fidelity, simulate
 
 
@@ -54,7 +54,7 @@ def _pjit_mesh(R: int, G: int):
 
 def _print_degraded(ex):
     """Say so whenever the engine is not the one that was asked for."""
-    prov = getattr(ex, "provenance", None) or {}
+    prov = ex.provenance
     if prov.get("degraded"):
         print(f"DEGRADED: asked for {prov.get('requested_backend')}"
               f"{'+pallas' if prov.get('requested_use_pallas') else ''}, "
@@ -65,7 +65,7 @@ def _print_degraded(ex):
 
 
 def _print_storage_summary(ex):
-    snap = (getattr(ex, "provenance", None) or {}).get("storage")
+    snap = ex.provenance.get("storage")
     if snap:
         print(f"storage: {snap['spilled_shards']}/{snap['n_shards']} shards "
               f"at rest on disk after run; {snap['spills']} spills, "
@@ -109,7 +109,7 @@ def main(argv=None):
     ap.add_argument("--R", type=int, default=0)
     ap.add_argument("--G", type=int, default=0)
     ap.add_argument("--executor", default="pjit",
-                    choices=["pjit", "shardmap", "offload", "dense", "pergate"])
+                    choices=["pjit", "shardmap", "offload", "dense"])
     ap.add_argument("--staging", default="ilp", choices=["ilp", "greedy"])
     ap.add_argument("--kernelizer", default="dp", choices=["dp", "ordered", "greedy"])
     ap.add_argument("--pallas", action="store_true")
@@ -123,13 +123,10 @@ def main(argv=None):
     ap.set_defaults(opt=False)
     ap.add_argument("--autotune", action="store_true",
                     help="A/B-replay candidate plans first and serve the "
-                         "fastest (implies --engine; winner is cached)")
-    ap.add_argument("--engine", action="store_true",
-                    help="route through the unified ExecutionEngine + compile "
-                         "cache (repro.sim.engine.engine_for)")
+                         "fastest (the winner is cached)")
     ap.add_argument("--batch", type=int, default=1,
                     help="run a batch of B basis initial states through the "
-                         "engine's fused batch path (implies --engine)")
+                         "engine's fused batch path")
     ap.add_argument("--check", action="store_true", help="fidelity vs dense ref")
     ap.add_argument("--shots", type=int, default=0, help="sample N bitstrings")
     ap.add_argument("--seed", type=int, default=0, help="sampling PRNG seed")
@@ -138,8 +135,8 @@ def main(argv=None):
     ap.add_argument("--observable", action="append", default=[],
                     help='Pauli sum, e.g. "Z0 Z1 + 0.5*X2" (repeatable)')
     ap.add_argument("--storage", default=None, metavar="SPEC",
-                    help="tiered at-rest shard store for --executor offload "
-                         "(implies --engine): 'exact'|'bf16'|'int8' with "
+                    help="tiered at-rest shard store for --executor offload: "
+                         "'exact'|'bf16'|'int8' with "
                          "optional ':dram_kib=N', ':dir=PATH', ':tol=X' — "
                          "e.g. 'int8:dram_kib=4096'. Shards past the DRAM "
                          "budget spill to disk; see README 'Scaling past "
@@ -158,11 +155,11 @@ def main(argv=None):
                          "for parameterized families unless --sweep is given")
     ap.add_argument("--sweep", default=None, metavar="FILE.json",
                     help="run a parameter sweep: every point reuses ONE "
-                         "structural compile (implies --engine)")
+                         "structural compile")
     ap.add_argument("--vqe", default=None, metavar="OBSERVABLE",
                     help='minimize <H> over the circuit\'s free parameters '
                          'with Adam over adjoint-mode value_and_grad, e.g. '
-                         '--vqe "Z0 Z1 + Z1 Z2 + 0.5*X0" (implies --engine)')
+                         '--vqe "Z0 Z1 + Z1 Z2 + 0.5*X0"')
     ap.add_argument("--vqe-steps", type=int, default=30)
     ap.add_argument("--vqe-lr", type=float, default=0.1)
     ap.add_argument("--vqe-seed", type=int, default=0)
@@ -195,81 +192,51 @@ def main(argv=None):
                 over["error_tolerance"] = args.storage_tol
             if over:
                 storage = storage.with_overrides(**over)
-    use_engine = (args.engine or args.autotune or args.batch > 1
-                  or args.executor == "dense" or storage is not None
-                  or args.sweep is not None or args.vqe is not None)
-    if use_engine and args.executor == "pergate":
-        ap.error("--engine/--batch/--sweep do not support the pergate baseline")
-    if not use_engine and (binds or not circ.is_bound):
-        # legacy executor path: bind eagerly (the engine path binds lazily so
-        # the structural compile cache stays parameter-blind)
-        circ = circ.bind(binds)
-        binds = {}
-    # --check always cross-checks against the circuit as the user wrote it,
-    # never the optimizer's rewrite of it
-    ref_circ = circ
+    backend_kw = {"mesh": _pjit_mesh(args.R, args.G)} \
+        if args.executor == "pjit" else {}
+    t0 = time.time()
+    if args.autotune:
+        from ..core.autotune import autotune_engine
 
-    if use_engine:
-        from ..sim.engine import DEFAULT_CACHE, engine_for
-
-        backend_kw = {"mesh": _pjit_mesh(args.R, args.G)} \
-            if args.executor == "pjit" else {}
-        t0 = time.time()
-        if args.autotune:
-            from ..core.autotune import autotune_engine
-
-            res = autotune_engine(
-                circ, L, args.R, args.G, backend=args.executor,
-                use_pallas=args.pallas, backend_kw=backend_kw)
-            print(f"autotune: chose '{res.chosen}' "
-                  f"({res.speedup_vs_default:.2f}x vs default, "
-                  f"{len(res.replay_us)} candidates, "
-                  f"{res.tune_time_s:.1f}s"
-                  f"{', cached' if res.cached else ''})")
-        ex = engine_for(
+        res = autotune_engine(
             circ, L, args.R, args.G, backend=args.executor,
-            use_pallas=args.pallas, staging_method=args.staging,
-            kernelize_method=args.kernelizer, optimize=args.opt,
-            backend_kw=backend_kw, storage=storage,
-        )
-        plan = ex.plan
-        _print_degraded(ex)
-        st_cfg = getattr(ex.backend, "storage", None)
-        if st_cfg is not None:
-            budget = ("unbounded" if st_cfg.dram_bytes is None
-                      else f"{st_cfg.dram_bytes / (1 << 20):.1f} MiB")
-            print(f"storage: at-rest {st_cfg.at_rest_dtype}, DRAM budget "
-                  f"{budget}, tol {st_cfg.error_tolerance}")
-        print(f"engine[{ex.backend.name}] ready in {time.time() - t0:.2f}s; "
-              f"cache: {len(DEFAULT_CACHE)} entries, {DEFAULT_CACHE.hits} hits"
-              f"/{DEFAULT_CACHE.misses} misses")
-        opt_prov = getattr(ex, "provenance", {}).get("optimize")
-        if opt_prov:
-            print(f"optimizer: {opt_prov['gates_before']} -> "
-                  f"{opt_prov['gates_after']} gates "
-                  f"(-{opt_prov['gates_removed']}; "
-                  f"passes: {opt_prov['pass_counts']})")
-        if binds:
-            t0 = time.time()
-            ex.bind(binds)
-            print(f"bound {len(binds)} params in {time.time() - t0:.3f}s "
-                  "(tensor swap: no ILP/DP/XLA)")
-        elif not circ.is_bound and args.sweep is None and args.vqe is None:
-            ap.error(f"circuit has free parameters {circ.param_names}; "
-                     "pass --bind NAME=VAL, --sweep FILE.json or --vqe OBS")
-    else:
-        if args.opt:
-            from ..core.optimize import optimize_circuit
-
-            ores = optimize_circuit(circ)
-            print(f"optimizer: {ores.source.n_gates} -> "
-                  f"{ores.circuit.n_gates} gates (-{ores.gates_removed}; "
-                  f"passes: {ores.pass_counts()})")
-            circ = ores.circuit
+            use_pallas=args.pallas, backend_kw=backend_kw)
+        print(f"autotune: chose '{res.chosen}' "
+              f"({res.speedup_vs_default:.2f}x vs default, "
+              f"{len(res.replay_us)} candidates, "
+              f"{res.tune_time_s:.1f}s"
+              f"{', cached' if res.cached else ''})")
+    ex = engine_for(
+        circ, L, args.R, args.G, backend=args.executor,
+        use_pallas=args.pallas, staging_method=args.staging,
+        kernelize_method=args.kernelizer, optimize=args.opt,
+        backend_kw=backend_kw, storage=storage,
+    )
+    plan = ex.plan
+    _print_degraded(ex)
+    st_cfg = getattr(ex.backend, "storage", None)
+    if st_cfg is not None:
+        budget = ("unbounded" if st_cfg.dram_bytes is None
+                  else f"{st_cfg.dram_bytes / (1 << 20):.1f} MiB")
+        print(f"storage: at-rest {st_cfg.at_rest_dtype}, DRAM budget "
+              f"{budget}, tol {st_cfg.error_tolerance}")
+    print(f"engine[{ex.backend.name}] ready in {time.time() - t0:.2f}s; "
+          f"cache: {len(DEFAULT_CACHE)} entries, {DEFAULT_CACHE.hits} hits"
+          f"/{DEFAULT_CACHE.misses} misses")
+    opt_prov = ex.provenance.get("optimize")
+    if opt_prov:
+        print(f"optimizer: {opt_prov['gates_before']} -> "
+              f"{opt_prov['gates_after']} gates "
+              f"(-{opt_prov['gates_removed']}; "
+              f"passes: {opt_prov['pass_counts']})")
+    if binds:
         t0 = time.time()
-        plan = partition(circ, L, args.R, args.G,
-                         staging_method=args.staging,
-                         kernelize_method=args.kernelizer)
+        ex.bind(binds)
+        print(f"bound {len(binds)} params in {time.time() - t0:.3f}s "
+              "(tensor swap: no ILP/DP/XLA)")
+    elif not circ.is_bound and args.sweep is None and args.vqe is None:
+        ap.error(f"circuit has free parameters {circ.param_names}; "
+                 "pass --bind NAME=VAL, --sweep FILE.json or --vqe OBS")
     print(f"partition: {plan.n_stages} stages, kernel cost {plan.total_kernel_cost:,.0f} us"
           f" (preprocess {plan.preprocess_time_s:.2f}s)")
 
@@ -389,43 +356,19 @@ def main(argv=None):
     # ------------------------------------------------------ single-state path
     t0 = time.time()
     measurer = None
-    if not use_engine:
-        if args.executor == "pjit":
-            from ..sim.executor import StagedExecutor
-
-            ex = StagedExecutor(circ, plan, mesh=_pjit_mesh(args.R, args.G))
-        elif args.executor == "shardmap":
-            from ..sim.shardmap_executor import ShardMapExecutor
-
-            ex = ShardMapExecutor(circ, plan, use_pallas=args.pallas)
-        elif args.executor == "offload":
-            from ..sim.offload import OffloadedExecutor
-
-            ex = OffloadedExecutor(circ, plan)
-        else:
-            from ..sim.offload import PerGateOffloadExecutor
-
-            ex = PerGateOffloadExecutor(circ, L)
-        _print_degraded(ex)
-    if args.executor == "pergate":
-        out = ex.run()
-    else:
-        out = ex.run_packed() if measuring else ex.run()
+    out = ex.run_packed() if measuring else ex.run()
     if measuring:
-        from ..sim.measure import Frame, measurer_for
+        from ..sim.measure import measurer_for
 
         # measured runs stay distributed/packed: never gather 2^n amplitudes
         out = jax.block_until_ready(out) if not isinstance(out, np.ndarray) else out
-        frame = (ex.measurement_frame if args.executor != "pergate"
-                 else Frame.identity(n))
-        measurer = measurer_for(out, frame)
+        measurer = measurer_for(out, ex.measurement_frame)
     else:
         out = np.asarray(jax.block_until_ready(out)) if not isinstance(out, np.ndarray) else out
     dt = time.time() - t0
     print(f"simulated in {dt:.3f}s ({circ.n_gates / dt:,.0f} gates/s, "
           f"{2**n / dt / 1e6:,.1f} Mamps/s)")
-    if use_engine:
-        _print_storage_summary(ex)
+    _print_storage_summary(ex)
 
     if measurer is not None:
         from ..sim.measure import measure_to_result
@@ -452,9 +395,10 @@ def main(argv=None):
         if measurer is not None:
             # measured runs keep the final-stage layout; re-run with the
             # final remap applied for the logical-order fidelity check
-            out = ex.run() if args.executor != "pergate" else out
+            out = ex.run()
             out = np.asarray(jax.block_until_ready(out)) if not isinstance(out, np.ndarray) else out
-        ref = simulate(ref_circ if ref_circ.is_bound else ref_circ.bind(binds))
+        # the circuit as the user wrote it, never the optimizer's rewrite
+        ref = simulate(circ if circ.is_bound else circ.bind(binds))
         print(f"fidelity vs dense reference: {fidelity(out, ref):.6f}")
     return out
 

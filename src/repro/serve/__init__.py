@@ -7,7 +7,6 @@ Entry points:
 * :class:`SimulationService` + :class:`ServeConfig` — the asyncio loop.
 * :class:`SimRequest` / :class:`SimResponse` — the request/response shapes.
 * ``python -m repro.launch.serve_sim`` — TCP front-end / demo driver.
-* ``python -m benchmarks.bench_serve`` — synthetic heavy-traffic harness.
 """
 
 from .batcher import (  # noqa: F401

@@ -2,9 +2,9 @@
 histograms — one JSON-able snapshot for the whole request path.
 
 The serving loop is instrumented at every stage boundary (queue-wait,
-batch-form, bind/acquire, execute, measure, end-to-end) and the load harness
-(``benchmarks/bench_serve.py``) asserts throughput/tail-latency off the same
-snapshot the service itself exposes — there is no second bookkeeping path.
+batch-form, bind/acquire, execute, measure, end-to-end) and the serving
+tests read the same snapshot the service itself exposes — there is no
+second bookkeeping path.
 
 Design constraints:
 

@@ -25,8 +25,8 @@ shared core:
   repeated traffic skips ILP staging + DP kernelization + stage compilation +
   XLA compilation after the first request.
 
-The legacy executor modules (``executor``, ``shardmap_executor``,
-``offload``) survive as thin compatibility shims over this engine.
+Every caller builds its engine through :func:`engine_for`, or constructs
+:class:`ExecutionEngine` directly when it holds a backend instance.
 
 Adding a backend = subclass :class:`Backend`, implement ``prepare`` /
 ``execute`` (+ optionally ``execute_batch`` for a fused batch path), and
@@ -364,8 +364,8 @@ def _build_shard_fn(op_shapes: Tuple, L: int, batched: bool = False,
 class JitCache:
     """Bounded LRU of compiled functions.
 
-    Replaces the old module-level ``@lru_cache(maxsize=None)`` in
-    ``offload.py``: unbounded per-process caches of jitted executables leak
+    Replaces a module-level ``@lru_cache(maxsize=None)`` of shard
+    functions: unbounded per-process caches of jitted executables leak
     compiled programs in long-running serving processes. One instance lives on
     each backend, so dropping the engine drops its executables too.
     """

@@ -21,6 +21,16 @@ def rng():
     return np.random.default_rng(0)
 
 
+def planned_engine(circuit, plan, backend="pjit", **kw):
+    """The engine for an explicit plan, built by ``engine_for`` with the
+    degradation ladder off: a backend that fails to build fails the test
+    instead of falling back to another one."""
+    from repro.sim.engine import engine_for
+
+    return engine_for(circuit, plan.L, plan.R, plan.G, plan=plan,
+                      backend=backend, degrade=False, **kw)
+
+
 def assert_states_close(a, b, atol=1e-5, msg=""):
     """Global-phase-insensitive state-vector comparison.
 

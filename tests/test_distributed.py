@@ -1,4 +1,4 @@
-"""Distributed executor tests — run in subprocesses with their own
+"""Distributed engine tests — run in subprocesses with their own
 XLA_FLAGS so the main pytest process keeps a single device."""
 
 import os
@@ -23,18 +23,19 @@ def run_sub(code: str, devices: int = 8, timeout: int = 900) -> str:
 
 
 @pytest.mark.slow
-def test_shardmap_executor_families():
+def test_shardmap_backend_families():
     out = run_sub(
         """
 import jax
 from repro.core import generators as gen
 from repro.core.partition import partition
 from repro.sim.statevector import simulate, fidelity
-from repro.sim.shardmap_executor import ShardMapExecutor
+from repro.sim.engine import engine_for
 for fam in ['qft', 'ising', 'qsvm', 'wstate']:
     c = gen.FAMILIES[fam](9)
     plan = partition(c, 6, 2, 1)
-    f = fidelity(ShardMapExecutor(c, plan).run(), simulate(c))
+    ex = engine_for(c, 6, 2, 1, plan=plan, backend='shardmap', degrade=False)
+    f = fidelity(ex.run(), simulate(c))
     assert f > 0.9999, (fam, f)
 print('OK')
 """
@@ -50,10 +51,12 @@ def test_shardmap_pallas_path():
 from repro.core import generators as gen
 from repro.core.partition import partition
 from repro.sim.statevector import simulate, fidelity
-from repro.sim.shardmap_executor import ShardMapExecutor
+from repro.sim.engine import engine_for
 c = gen.ising(9)
 plan = partition(c, 6, 2, 1)
-f = fidelity(ShardMapExecutor(c, plan, use_pallas=True).run(), simulate(c))
+ex = engine_for(c, 6, 2, 1, plan=plan, backend='shardmap', use_pallas=True,
+                degrade=False)
+f = fidelity(ex.run(), simulate(c))
 assert f > 0.9999, f
 print('OK')
 """
@@ -68,11 +71,12 @@ def test_shardmap_random_circuits_with_flips():
 from repro.core import generators as gen
 from repro.core.partition import partition
 from repro.sim.statevector import simulate, fidelity
-from repro.sim.shardmap_executor import ShardMapExecutor
+from repro.sim.engine import engine_for
 for seed in range(4):
     c = gen.random_circuit(8, 45, seed=seed)
     plan = partition(c, 5, 2, 1)
-    f = fidelity(ShardMapExecutor(c, plan).run(), simulate(c))
+    ex = engine_for(c, 5, 2, 1, plan=plan, backend='shardmap', degrade=False)
+    f = fidelity(ex.run(), simulate(c))
     assert f > 0.9999, (seed, f)
 print('OK')
 """
@@ -88,10 +92,11 @@ def test_shardmap_collective_schedule():
 import re
 from repro.core import generators as gen
 from repro.core.partition import partition
-from repro.sim.shardmap_executor import ShardMapExecutor
+from repro.sim.engine import engine_for
 c = gen.qft(9)
 plan = partition(c, 6, 2, 1)
-hlo = ShardMapExecutor(c, plan).lower().compile().as_text()
+ex = engine_for(c, 6, 2, 1, plan=plan, backend='shardmap', degrade=False)
+hlo = ex.lower().compile().as_text()
 kinds = set(re.findall(r'(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)', hlo))
 assert 'all-gather' not in kinds and 'all-reduce' not in kinds, kinds
 assert 'all-to-all' in kinds
@@ -109,12 +114,14 @@ import jax
 from repro.core import generators as gen
 from repro.core.partition import partition
 from repro.sim.statevector import simulate, fidelity
-from repro.sim.executor import StagedExecutor
+from repro.sim.engine import engine_for
 mesh = jax.make_mesh((2, 2, 2), ('pod', 'data', 'model'),
                      axis_types=(jax.sharding.AxisType.Auto,) * 3)
 c = gen.qft(9)
 plan = partition(c, 6, 2, 1)
-f = fidelity(StagedExecutor(c, plan, mesh=mesh).run(), simulate(c))
+ex = engine_for(c, 6, 2, 1, plan=plan, backend_kw={'mesh': mesh},
+                degrade=False)
+f = fidelity(ex.run(), simulate(c))
 assert f > 0.9999, f
 print('OK')
 """

@@ -24,11 +24,10 @@ from repro.sim.engine import (
     CircuitKey,
     CompileCache,
     ExecutionEngine,
+    HostOffloadBackend,
     JitCache,
     engine_for,
 )
-from repro.sim.executor import StagedExecutor
-from repro.sim.offload import OffloadedExecutor
 from conftest import assert_states_close
 from repro.sim.statevector import fidelity, simulate_np
 
@@ -117,18 +116,6 @@ print('OK')
                        text=True, timeout=900, env=env)
     assert r.returncode == 0, f"subprocess failed:\n{r.stdout}\n{r.stderr[-3000:]}"
     assert "OK" in r.stdout
-
-
-# ---------------------------------------------------- shim bit-identicality
-def test_legacy_shims_bit_identical_to_engine(qft_case):
-    """The legacy executor entry points ARE the engine path: results must be
-    bit-identical, not merely close."""
-    c, plan = qft_case
-    eng_pjit = np.asarray(ExecutionEngine(c, plan, backend="pjit").run())
-    np.testing.assert_array_equal(np.asarray(StagedExecutor(c, plan).run()),
-                                  eng_pjit)
-    eng_off = ExecutionEngine(c, plan, backend="offload").run()
-    np.testing.assert_array_equal(OffloadedExecutor(c, plan).run(), eng_off)
 
 
 def test_packed_layouts_agree_across_backends(qft_case):
@@ -232,12 +219,12 @@ def test_offload_backend_jit_cache_is_instance_bounded(qft_case):
     """The old module-level lru_cache(maxsize=None) is gone: each offload
     backend owns a bounded cache that dies with the engine."""
     c, plan = qft_case
-    ex1 = OffloadedExecutor(c, plan, jit_cache_size=3)
-    ex2 = OffloadedExecutor(c, plan)
+    ex1 = ExecutionEngine(c, plan, backend=HostOffloadBackend(jit_cache_size=3))
+    ex2 = ExecutionEngine(c, plan, backend="offload")
     ex1.run()
-    assert 0 < len(ex1.engine.backend.jit_cache) <= 3
-    assert len(ex2.engine.backend.jit_cache) == 0, "caches must not be shared"
-    assert ex2.engine.backend.jit_cache.maxsize == 64
+    assert 0 < len(ex1.backend.jit_cache) <= 3
+    assert len(ex2.backend.jit_cache) == 0, "caches must not be shared"
+    assert ex2.backend.jit_cache.maxsize == 64
 
 
 # ----------------------------------------------------- plan serialization

@@ -12,10 +12,9 @@ from repro.core import gates as G
 from repro.core import generators as gen
 from repro.core.partition import partition
 from repro.sim import measure as M
-from repro.sim.executor import StagedExecutor
-from repro.sim.offload import OffloadedExecutor
 from repro.sim.result import SimulationResult, index_to_bitstring
 from repro.sim.statevector import simulate_np
+from conftest import planned_engine
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
@@ -111,14 +110,14 @@ def test_backend_equivalence(case):
     plan = partition(c, L, R, Gb)
     obs = OBS if n > 6 else OBS.replace("6", "5")
 
-    ex = StagedExecutor(c, plan)
+    ex = planned_engine(c, plan)
     frame = ex.measurement_frame
     measurers = {
         "pjit": M.ShardedMeasurer(ex.run_packed(), frame),
     }
-    off = OffloadedExecutor(c, plan)
+    off = planned_engine(c, plan, backend="offload")
     measurers["offload"] = M.StreamingMeasurer(
-        off.run(apply_final_remap=False), off.measurement_frame
+        off.run_packed(), off.measurement_frame
     )
     # dense oracle re-stored in the same frame: bit-for-bit comparable
     measurers["oracle"] = M.DenseMeasurer.with_frame(psi, frame)
@@ -158,7 +157,7 @@ def test_no_global_probability_vector_on_device_path():
     """Sampling must touch only shard masses + the locally sampled rows."""
     c = gen.qft(8)
     plan = partition(c, 5, 2, 1)
-    ex = StagedExecutor(c, plan)
+    ex = planned_engine(c, plan)
     m = M.ShardedMeasurer(ex.run_packed(), ex.measurement_frame)
     calls = []
     orig = m._local_probs
@@ -205,13 +204,13 @@ import numpy as np
 from repro.core import generators as gen
 from repro.core.partition import partition
 from repro.sim import measure as M
-from repro.sim.shardmap_executor import ShardMapExecutor
+from repro.sim.engine import engine_for
 from repro.sim.statevector import simulate_np
 
 c = gen.random_circuit(8, 40, seed=3)
 psi = simulate_np(c)
 plan = partition(c, 5, 2, 1)
-ex = ShardMapExecutor(c, plan)
+ex = engine_for(c, 5, 2, 1, plan=plan, backend="shardmap", degrade=False)
 m = M.ShardedMeasurer(ex.run_packed(), ex.measurement_frame)
 obs = "Z0 Z1 + 0.5*X2 Y6 - 1.5*Y0 X3 + 2.0"
 assert abs(m.expectation(obs) - M.expectation_np(psi, obs)) < 1e-5

@@ -386,6 +386,34 @@ def test_engine_optimize_state_equivalence(backend):
     assert prov["gates_after"] == prov["gates_before"] - prov["gates_removed"]
 
 
+@pytest.mark.parametrize("family", ["redundant", "qft", "su2random"])
+def test_optimizer_bars_on_families(family):
+    """On the generator families the optimizer never adds gates and the
+    optimized engine reproduces the literal engine's state; on the
+    cancellation-rich ``redundant`` family it removes gates and the plan
+    has strictly fewer stages (qft and su2random have nothing to remove)."""
+    from repro.core.generators import FAMILIES
+    from repro.sim.engine import CompileCache, engine_for
+
+    n, L, R = 10, 8, 2
+    c = FAMILIES[family](n)
+    cache = CompileCache(maxsize=4)
+    lit = engine_for(c, L, R, 0, cache=cache, degrade=False)
+    opt = engine_for(c, L, R, 0, cache=cache, optimize=True, degrade=False)
+    prov = opt.provenance["optimize"]
+    assert prov["gates_before"] == c.n_gates
+    assert prov["gates_after"] <= prov["gates_before"]
+    a = np.asarray(lit.run(), dtype=np.complex128)
+    b = np.asarray(opt.run(), dtype=np.complex128)
+    infidelity = 1.0 - abs(np.vdot(a, b)) / (np.linalg.norm(a)
+                                             * np.linalg.norm(b))
+    assert infidelity < 1e-6, f"{family}: infidelity {infidelity:.3e}"
+    if family == "redundant":
+        assert prov["gates_removed"] > 0
+        assert opt.plan.n_stages < lit.plan.n_stages, \
+            (lit.plan.n_stages, opt.plan.n_stages)
+
+
 def test_optimized_symbolic_warm_rebind_zero_solves_zero_retraces():
     from repro.sim.engine import CompileCache, engine_for
     from repro.sim.statevector import simulate_np

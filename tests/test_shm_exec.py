@@ -20,10 +20,7 @@ from repro.core import generators as gen
 from repro.core.partition import partition
 from repro.kernels import ops as kops
 from repro.sim.compile import compile_plan
-from repro.sim.executor import StagedExecutor
-from repro.sim.offload import OffloadedExecutor
-from repro.sim.shardmap_executor import ShardMapExecutor
-from conftest import assert_states_close
+from conftest import assert_states_close, planned_engine
 from repro.sim.statevector import fidelity, simulate_np
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -66,7 +63,7 @@ def test_peephole_reduces_passes_and_preserves_state():
     assert fused.total_gates == raw.total_gates
     ref = simulate_np(c)
     for peep in (True, False):
-        ex = OffloadedExecutor(c, plan, peephole=peep)
+        ex = planned_engine(c, plan, backend="offload", peephole=peep)
         assert_states_close(ex.run(), ref)
 
 
@@ -75,7 +72,7 @@ def test_shm_group_is_one_pallas_call():
     c = gen.qft(7)
     plan = partition(c, 7, 0, 0, cost_model=SHM_CM)
     kops.reset_kernel_counters()
-    ex = ShardMapExecutor(c, plan, use_pallas=True)
+    ex = planned_engine(c, plan, backend="shardmap", use_pallas=True)
     ex.lower()  # trace without executing
     counts = kops.kernel_call_counts()
     n_shm = _n_shm_ops(ex.cc)
@@ -92,7 +89,7 @@ def test_shardmap_pallas_shm_matches_oracle_single_device():
     c = gen.qft(7)
     plan = partition(c, 7, 0, 0, cost_model=SHM_CM)
     ref = jnp.asarray(simulate_np(c))
-    ex = ShardMapExecutor(c, plan, use_pallas=True)
+    ex = planned_engine(c, plan, backend="shardmap", use_pallas=True)
     assert _n_shm_ops(ex.cc) >= 1
     assert_states_close(ex.run(), ref)
 
@@ -103,7 +100,7 @@ def test_staged_executor_pallas_shm_dep_batched():
     c = gen.qft(8)
     plan = partition(c, 6, 2, 0, cost_model=SHM_CM)
     ref = jnp.asarray(simulate_np(c))
-    ex = StagedExecutor(c, plan, use_pallas=True)
+    ex = planned_engine(c, plan, use_pallas=True)
     shm_ops = [op for p in ex.cc.programs for op in p.ops if op.kind == "shm"]
     assert shm_ops
     assert any(m.dep_bits for op in shm_ops for m in op.gates), \
@@ -117,7 +114,7 @@ def test_staged_executor_pallas_shm_random_with_flips(seed):
     c = gen.random_circuit(8, 40, seed=seed)
     plan = partition(c, 5, 2, 1, cost_model=SHM_CM)
     ref = jnp.asarray(simulate_np(c))
-    ex = StagedExecutor(c, plan, use_pallas=True)
+    ex = planned_engine(c, plan, use_pallas=True)
     assert_states_close(ex.run(), ref)
 
 
@@ -130,13 +127,14 @@ from repro.core import generators as gen
 from repro.core.cost_model import CostModel
 from repro.core.partition import partition
 from repro.kernels import ops as kops
-from repro.sim.shardmap_executor import ShardMapExecutor
+from repro.sim.engine import engine_for
 from repro.sim.statevector import simulate, fidelity
 cm = CostModel(mxu_us_per_2k=1e7, shm_gate_us=1.0, shm_diag_gate_us=0.5)
 c = gen.qft(8)
 plan = partition(c, 6, 2, 0, cost_model=cm)
 kops.reset_kernel_counters()
-ex = ShardMapExecutor(c, plan, use_pallas=True)
+ex = engine_for(c, 6, 2, 0, plan=plan, backend='shardmap', use_pallas=True,
+               degrade=False)
 f = fidelity(ex.run(), simulate(c))
 assert f > 0.9999, f
 n_shm = sum(1 for p in ex.cc.programs for op in p.ops if op.kind == 'shm')
@@ -144,7 +142,9 @@ assert n_shm >= 1
 assert kops.kernel_call_counts()['shm'] == n_shm
 c2 = gen.random_circuit(8, 45, seed=3)
 plan2 = partition(c2, 5, 2, 1, cost_model=cm)
-f2 = fidelity(ShardMapExecutor(c2, plan2, use_pallas=True).run(), simulate(c2))
+ex2 = engine_for(c2, 5, 2, 1, plan=plan2, backend='shardmap', use_pallas=True,
+                degrade=False)
+f2 = fidelity(ex2.run(), simulate(c2))
 assert f2 > 0.9999, f2
 print('OK')
 """
@@ -161,7 +161,7 @@ def test_offload_prestages_tensors_and_overlaps():
     c = gen.qft(9)
     plan = partition(c, 6, 3, 0)
     ref = jnp.asarray(simulate_np(c))
-    ex = OffloadedExecutor(c, plan)
+    ex = planned_engine(c, plan, backend="offload")
     out = ex.run()
     assert_states_close(out, ref)
     st = ex.stats
@@ -184,6 +184,6 @@ def test_offload_shm_plan_matches_oracle():
     c = gen.qft(8)
     plan = partition(c, 6, 2, 0, cost_model=SHM_CM)
     ref = jnp.asarray(simulate_np(c))
-    ex = OffloadedExecutor(c, plan)
+    ex = planned_engine(c, plan, backend="offload")
     assert _n_shm_ops(ex.cc) >= 1
     assert_states_close(ex.run(), ref)

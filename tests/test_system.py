@@ -14,8 +14,7 @@ import pytest
 from repro.core import generators as gen
 from repro.core.partition import partition
 from repro.launch import hlo_analysis as ha
-from repro.sim.executor import StagedExecutor
-from conftest import assert_states_close
+from conftest import assert_states_close, planned_engine
 from repro.sim.statevector import fidelity, simulate
 
 
@@ -28,7 +27,7 @@ def test_end_to_end_paper_pipeline():
                             kernelize_method="greedy")
     assert plan_dp.n_stages <= plan_greedy.n_stages
     assert plan_dp.total_kernel_cost < plan_greedy.total_kernel_cost
-    out = StagedExecutor(c, plan_dp).run()
+    out = planned_engine(c, plan_dp).run()
     assert_states_close(out, simulate(c))
 
 
@@ -39,7 +38,7 @@ def test_communication_only_between_stages():
 
     c = gen.qft(10)
     plan = partition(c, 7, 2, 1)
-    ex = StagedExecutor(c, plan, donate=False)
+    ex = planned_engine(c, plan, backend_kw={"donate": False})
     hlo = ex.lower().compile().as_text()
     assert not re.search(r"all-to-all|all-reduce|all-gather", hlo)
 
